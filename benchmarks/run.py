@@ -929,9 +929,20 @@ def bench_partition():
     parity, and the collective counts the PartitionGraph pass recorded
     (``PipelineReport.stats["partition"]``).  On host CPU the tp=2 leg
     pays collective overhead rather than gaining speed — the row pair is
-    a memory/parity claim, not a CPU speedup claim."""
+    a memory/parity claim, not a CPU speedup claim.
+
+    Only on a CPU host: a parent that has touched JAX holds an
+    accelerator, and a child started from it could not reach the chip."""
     import subprocess
 
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"E17 forces a 2-device CPU mesh in a child process, which "
+            f"cannot share the {jax.default_backend()} this process holds; "
+            f"on a chip host run the tp path in-process with "
+            f"`python chip_smoke.py --tp 4`")
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
@@ -1048,6 +1059,9 @@ SECTIONS = {
 
 
 def main() -> None:
+    from repro.runtime.jax_cache import use_jax_compile_cache
+
+    use_jax_compile_cache()
     which = sys.argv[1:] or list(SECTIONS)
     print("section,name,value,unit")
     for name in which:

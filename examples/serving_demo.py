@@ -41,11 +41,12 @@ The sixth section is the PR 10 unified sharding API: one module
 the partition profiles that drive the ``PartitionGraph`` pass —
 ``CompileOptions(mode="shardmap", partition="tp", mesh_shape=(N,))``
 cuts a compiled graph into a per-device program with explicit AllGather
-nodes (the exact column-parallel profile never splits a contraction,
-so greedy decode stays bit-identical), and ``EngineConfig(tp=2)``
-serves the paged engine tensor-parallel: each device holds half the KV
-heads of every page while greedy tokens match ``tp=1`` exactly.  The
-tp half runs in a subprocess with a forced 2-device CPU mesh.
+nodes (the column-parallel profile never splits a contraction), and
+``EngineConfig(tp=2)`` serves the paged engine tensor-parallel: each
+device holds half the KV heads of every page while greedy tokens match
+``tp=1`` up to near-ties of the logits.  The tp half runs in a
+subprocess with a forced 2-device CPU mesh, so only on a CPU host; on an
+accelerator host it points to ``chip_smoke.py --tp 4`` instead.
 
 The final section shows the fused-kernel layer underneath: compiling a
 serve-family graph at O2 pattern-matches the unfused matmul chains into
@@ -139,6 +140,8 @@ def tensor_parallel_demo(cfg):
     import subprocess
     import sys
 
+    import jax
+
     from repro.backend import Backend, CompileOptions
     from repro.backend.sharding import partition_profile
     from repro.configs.base import ShapeConfig
@@ -148,7 +151,7 @@ def tensor_parallel_demo(cfg):
     prof = partition_profile("tp")
     print(f"profile 'tp': axes={prof.axes} rules={prof.rules} "
           f"last_dim_only={prof.last_dim_only} (column-parallel only: "
-          f"never splits a contraction, so greedy decode is bit-exact)")
+          f"never splits a contraction)")
     # the partition pass runs inside Backend.compile; on a trivial (1,)
     # mesh it only annotates — the stats show what a real mesh would cut
     g = build_graphs(cfg, ShapeConfig("serve", "serve", 16, 2), 2)
@@ -157,8 +160,14 @@ def tensor_parallel_demo(cfg):
                              mesh_shape=(1,), static_jit=False))
     print(f"pipeline stats['partition']: "
           f"{dict(cf.report.stats['partition'])}")
-    # the real 2-device serve needs the flag set before jax imports,
-    # so it runs in a child process (exactly what CI's serving-tp does)
+    if jax.default_backend() != "cpu":
+        # this process holds the accelerator, so a child could not reach it
+        print(f"tp serving skipped: it runs in a forced-CPU child, which "
+              f"cannot share the {jax.default_backend()} this process "
+              f"holds; on a chip host run `python chip_smoke.py --tp 4`")
+        return
+    # a 2-device CPU mesh needs the flag set before jax imports, so it
+    # runs in a child process (exactly what CI's serving-tp does)
     proc = subprocess.run([sys.executable, "-c", _TP_CHILD],
                           capture_output=True, text=True, timeout=600)
     print(proc.stdout.rstrip() if proc.returncode == 0

@@ -11,7 +11,8 @@ same seed), so the tokens the server streams here are the tokens the
 direct-engine matrix legs decode — the report the server writes after
 SIGTERM is then parity-checked by ``scripts/check_serving_matrix.py``.
 Exit code 1 if any client fails, errors mid-stream, or gets anything
-but 200.
+but 200.  This mode never initialises a JAX backend: the server process
+holds the accelerator, and only one process may.
 
 ``python scripts/serve_load.py --probe-backpressure``
 builds its own in-process server sized to make every rejection

@@ -20,8 +20,4 @@ from .compiled import CompiledFunction  # noqa: F401
 from .diskcache import DiskCompileCache  # noqa: F401
 from .options import CompileOptions, OptionsError  # noqa: F401
 from . import interpreter as _interpreter  # noqa: F401  (registers itself)
-
-try:  # jax backend registers on import; interpreter works without jax
-    from . import jax_backend as _jax_backend  # noqa: F401
-except ImportError:  # pragma: no cover
-    pass
+from . import jax_backend as _jax_backend  # noqa: F401  (registers itself)

@@ -139,17 +139,12 @@ class JaxBackend(Backend):
         partition pass stamped on the graph.  Callers keep passing global
         arrays; jit splits them per ``in_specs`` (and donation keeps the
         sharded KV chain device-resident across dispatches)."""
+        import jax
         from jax.sharding import PartitionSpec
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # newer jax spells it jax.shard_map
-            from jax import shard_map
 
-        def spec_of(p):
-            ps = p.attrs.get("pspec") or (None,) * len(p.out_types[0].shape)
-            return PartitionSpec(*ps)
+        from .sharding import partition_spec
 
-        in_specs = tuple(spec_of(p) for p in fn.parameters)
+        in_specs = tuple(partition_spec(p) for p in fn.parameters)
         out_specs = []
         for r in fn.results:
             ps = r.node.attrs.get("out_pspecs")
@@ -159,12 +154,8 @@ class JaxBackend(Backend):
         def as_tuple(*args):
             return tuple(run(*args))
 
-        try:
-            wrapped = shard_map(as_tuple, mesh=mesh, in_specs=in_specs,
-                                out_specs=tuple(out_specs), check_rep=False)
-        except TypeError:  # check_rep renamed/removed
-            wrapped = shard_map(as_tuple, mesh=mesh, in_specs=in_specs,
-                                out_specs=tuple(out_specs))
+        wrapped = jax.shard_map(as_tuple, mesh=mesh, in_specs=in_specs,
+                                out_specs=tuple(out_specs), check_vma=False)
 
         def as_list(*args):
             return list(wrapped(*args))

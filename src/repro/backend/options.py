@@ -52,7 +52,8 @@ class CompileOptions:
     partition: Optional[str] = None
     mesh_shape: Optional[Tuple[int, ...]] = None
     use_pallas: bool = False             # compound ops as Pallas kernels
-    interpret_pallas: bool = True        # Pallas interpret mode (CPU-safe)
+    interpret_pallas: bool = False       # Pallas interpret mode (CPU only,
+                                         # and only when asked for by name)
     remat_scan: bool = False             # checkpoint scan bodies
     attn_impl: str = "auto"              # 'auto' | 'naive' | 'chunked'
     attn_chunk: int = 1024

@@ -177,9 +177,11 @@ class PartitionProfile:
     ``last_dim_only=True`` is the serving tensor-parallel plan: only
     column-parallel weight shards (wq/wk/wv/w_gate/w_up on their output
     dim, rank-1 biases), with AllGather at the transitions back to
-    replicated weights — every arithmetic op then computes bit-identical
-    values to the single-device graph, which is what makes greedy
-    serving token-for-token reproducible across ``tp``.  Row-parallel
+    replicated weights — no contraction is split, so every op computes
+    the single-device graph's math.  The compiler may still order a
+    reduction differently for the per-device shapes: greedy streams
+    agree across ``tp`` except where they part at a near-tie of the
+    logits (seen on a TPU v5e at deepseek-7b widths).  Row-parallel
     cuts (AllReduce after the matmul) remain available to profiles with
     ``last_dim_only=False``; they re-round split bf16 contractions and
     so trade exactness for halved activations.
@@ -297,6 +299,26 @@ def mesh_for_options(options) -> Any:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} for a "
             f"CPU test mesh)")
     return Mesh(np.array(devs[:n]).reshape(options.mesh_shape), prof.axes)
+
+
+def partition_spec(param) -> Any:
+    """The PartitionSpec the PartitionGraph pass stamped on one graph
+    parameter (replicated when it stamped none)."""
+    from jax.sharding import PartitionSpec
+
+    ps = param.attrs.get("pspec") or (None,) * len(param.out_types[0].shape)
+    return PartitionSpec(*ps)
+
+
+def input_shardings(fn, options) -> Dict[str, Any]:
+    """Input name -> ``NamedSharding`` on the options' mesh for a graph
+    the PartitionGraph pass cut: where to place each global array once,
+    so the shard_map'd program never re-lays it out per dispatch."""
+    from jax.sharding import NamedSharding
+
+    mesh = mesh_for_options(options)
+    return {p.name: NamedSharding(mesh, partition_spec(p))
+            for p in fn.parameters}
 
 
 # ---------------------------------------------------------------------------

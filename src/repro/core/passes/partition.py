@@ -86,8 +86,8 @@ class PartitionGraph(Pass):
         self.last_dim_only = bool(last_dim_only)
         self.anywhere = frozenset(anywhere)
         # exact profiles never row-parallelize a contraction they could
-        # gather instead: split-contraction re-rounding would break
-        # bit-identical greedy serving (see backend.sharding docstring)
+        # gather instead: split-contraction re-rounding would move every
+        # logit, not just break near-ties (see backend.sharding docstring)
         self.exact = self.last_dim_only
 
     @classmethod

@@ -11,18 +11,12 @@ import dataclasses
 import math
 from typing import Any, Iterable, Sequence, Tuple
 
+import ml_dtypes  # bfloat16 et al. ship with jax
 import numpy as np
 
-try:  # bfloat16 et al. ship with jax
-    import ml_dtypes
-
-    bfloat16 = np.dtype(ml_dtypes.bfloat16)
-    float8_e4m3 = np.dtype(ml_dtypes.float8_e4m3fn)
-    float8_e5m2 = np.dtype(ml_dtypes.float8_e5m2)
-except ImportError:  # pragma: no cover - ml_dtypes always present with jax
-    bfloat16 = np.dtype(np.float32)
-    float8_e4m3 = np.dtype(np.float32)
-    float8_e5m2 = np.dtype(np.float32)
+bfloat16 = np.dtype(ml_dtypes.bfloat16)
+float8_e4m3 = np.dtype(ml_dtypes.float8_e4m3fn)
+float8_e5m2 = np.dtype(ml_dtypes.float8_e5m2)
 
 # Canonical element types, keyed by short name.
 DTYPES = {

@@ -1,3 +1,12 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the compound ops (see ``ops.py`` for selection)."""
+import jax
+
+
+def check_interpret(interpret: bool) -> bool:
+    """Interpret mode is for hosts without a TPU, and only when a caller
+    asks for it by name: on a TPU a kernel runs compiled or is refused."""
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError(
+            "Pallas interpret mode was requested on a TPU; kernels there "
+            "run compiled (pass interpret=False)")
+    return interpret

@@ -34,6 +34,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import check_interpret
+
 NEG_INF = -1e30
 _LANES = 128
 
@@ -168,6 +170,6 @@ def flash_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
-        interpret=interpret,
+        interpret=check_interpret(interpret),
         **kw,
     )(off, q, k, v)

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import check_interpret
+
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     @pl.when(pl.program_id(2) == 0)
@@ -59,5 +61,5 @@ def matmul(a: jax.Array, b: jax.Array, bm: int = 256, bn: int = 256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
         if not interpret else None,
-        interpret=interpret,
+        interpret=check_interpret(interpret),
     )(a, b)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import check_interpret
+
 
 def _norm_matmul_kernel(x_ref, g_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -53,5 +55,5 @@ def norm_matmul(x: jax.Array, g: jax.Array, w: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        interpret=interpret,
+        interpret=check_interpret(interpret),
     )(x, g, w)

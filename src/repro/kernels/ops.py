@@ -50,7 +50,7 @@ def rmsnorm_supported(shape: Tuple[int, ...]) -> bool:
 
 
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     return _rmsnorm(x, w, eps=eps, interpret=interpret)
 
 
@@ -59,7 +59,7 @@ def matmul_supported(m: int, k: int, n: int) -> bool:
     return m % _LANE == 0 and k % _LANE == 0 and n % _LANE == 0
 
 
-def matmul(a: jax.Array, b: jax.Array, interpret: bool = True, **kw) -> jax.Array:
+def matmul(a: jax.Array, b: jax.Array, interpret: bool = False, **kw) -> jax.Array:
     M, K = a.shape
     _, N = b.shape
     bm = _pick_block(M, kw.pop("bm", 256), _LANE) or M
@@ -69,20 +69,62 @@ def matmul(a: jax.Array, b: jax.Array, interpret: bool = True, **kw) -> jax.Arra
 
 
 # -- fused swiglu ---------------------------------------------------------------
-def swiglu_supported(m: int, d: int, f: int, do: int) -> bool:
-    """Fused MLP kernel: lane-aligned widths, sublane-aligned rows."""
+# Scoped VMEM a kernel may take by default is 16 MiB on v5e; the fused MLP
+# keeps its double-buffered working set under this budget, with headroom
+# for Mosaic's own scratch.
+_VMEM_BUDGET = 12 << 20
+
+
+def _swiglu_vmem(bm: int, d: int, bf: int, bn: int, itemsize: int) -> int:
+    """Bytes of VMEM one grid step holds: the double-buffered x row block,
+    gate/up/down weight blocks and output block, plus the f32 accumulator
+    and the f32 gate/up/hidden slabs."""
+    pipelined = bm * d + 2 * d * bf + bf * bn + bm * bn
+    return 2 * itemsize * pipelined + 4 * (bm * bn + 3 * bm * bf)
+
+
+def _swiglu_blocks(m: int, d: int, f: int, do: int, itemsize: int,
+                   bm: int, bn: int, bf: int) -> Optional[Tuple[int, int, int]]:
+    """Tiles near the requested ones that fit the VMEM budget: the ffn
+    block shrinks first, then the row block, then the output block.  None
+    when even the smallest aligned tiles do not fit."""
+    bm = _pick_block(m, bm, _SUBLANE) or m
+    bn = _pick_block(do, bn, _LANE) or do
+    bf = _pick_block(f, bf, _LANE) or f
+    while _swiglu_vmem(bm, d, bf, bn, itemsize) > _VMEM_BUDGET:
+        if bf > _LANE:
+            bf = _pick_block(f, bf // 2, _LANE) or _LANE
+        elif bm > _SUBLANE:
+            bm = _pick_block(m, bm // 2, _SUBLANE) or _SUBLANE
+        elif bn > _LANE:
+            bn = _pick_block(do, bn // 2, _LANE) or _LANE
+        else:
+            return None
+    return bm, bn, bf
+
+
+def swiglu_supported(m: int, d: int, f: int, do: int,
+                     itemsize: int = 4) -> bool:
+    """Fused MLP kernel: lane-aligned widths, sublane-aligned rows, and a
+    working set that fits VMEM at the smallest tiles."""
     return (d % _LANE == 0 and f % _LANE == 0 and do % _LANE == 0
-            and m % _SUBLANE == 0 and m > 0)
+            and m % _SUBLANE == 0 and m > 0
+            and _swiglu_blocks(m, d, f, do, itemsize, _SUBLANE, _LANE,
+                               _LANE) is not None)
 
 
 def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-           w_down: jax.Array, interpret: bool = True, **kw) -> jax.Array:
-    M, _D = x.shape
+           w_down: jax.Array, interpret: bool = False, **kw) -> jax.Array:
+    M, D = x.shape
     F = w_gate.shape[1]
     Do = w_down.shape[1]
-    bm = _pick_block(M, kw.pop("bm", 128), _SUBLANE) or M
-    bn = _pick_block(Do, kw.pop("bn", 256), _LANE) or Do
-    bf = _pick_block(F, kw.pop("bk", 256), _LANE) or F
+    blocks = _swiglu_blocks(M, D, F, Do, x.dtype.itemsize,
+                            kw.pop("bm", 128), kw.pop("bn", 256),
+                            kw.pop("bk", 256))
+    if blocks is None:
+        raise ValueError(f"swiglu ({M},{D})x({D},{F})x({F},{Do}) does not "
+                         f"fit VMEM; check swiglu_supported first")
+    bm, bn, bf = blocks
     return _swiglu(x, w_gate, w_up, w_down, bm=bm, bn=bn, bf=bf,
                    interpret=interpret)
 
@@ -93,7 +135,7 @@ def norm_matmul_supported(m: int, d: int, n: int) -> bool:
 
 
 def norm_matmul(x: jax.Array, g: jax.Array, w: jax.Array,
-                eps: float = 1e-6, interpret: bool = True,
+                eps: float = 1e-6, interpret: bool = False,
                 **kw) -> jax.Array:
     M, _D = x.shape
     N = w.shape[1]
@@ -120,7 +162,7 @@ def attention_supported(q_shape: Tuple[int, ...],
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
-                    q_offset=None, interpret: bool = True) -> jax.Array:
+                    q_offset=None, interpret: bool = False) -> jax.Array:
     B, Hq, Sq, Dk = q.shape
     Skv = k.shape[2]
     bq = _pick_block(Sq, 256, _LANE) or Sq

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import check_interpret
+
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -51,6 +53,6 @@ def rmsnorm_fwd(x: jax.Array, w: jax.Array, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=interpret,
+        interpret=check_interpret(interpret),
     )(x2, w)
     return out.reshape(orig_shape)

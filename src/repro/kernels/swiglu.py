@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import check_interpret
+
 
 def _swiglu_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
                    n_f: int):
@@ -26,13 +28,16 @@ def _swiglu_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]
-    # mirror ref.py's composition: f32 MXU accumulate, cast back to the
-    # input dtype between stages (bit-comparable with the XLA fallback)
+    # f32 MXU accumulate, cast back to the input dtype between stages.
+    # silu*mul runs on f32 values (Mosaic refuses the f32 constants of a
+    # bf16 sigmoid: vector.broadcast element-type mismatch), so a bf16
+    # kernel rounds differently from ref.py and the XLA emitter, which
+    # apply silu in the input dtype.
     g = jnp.dot(x, wg_ref[...],
                 preferred_element_type=jnp.float32).astype(x.dtype)
     u = jnp.dot(x, wu_ref[...],
                 preferred_element_type=jnp.float32).astype(x.dtype)
-    h = jax.nn.silu(g) * u
+    h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
     acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[...],
                             preferred_element_type=jnp.float32)
 
@@ -56,7 +61,8 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                     preferred_element_type=jnp.float32).astype(x.dtype)
         u = jnp.dot(x, w_up,
                     preferred_element_type=jnp.float32).astype(x.dtype)
-        return jnp.dot(jax.nn.silu(g) * u, w_down,
+        h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+        return jnp.dot(h.astype(x.dtype), w_down,
                        preferred_element_type=jnp.float32).astype(x.dtype)
     n_f = F // bf
     return pl.pallas_call(
@@ -74,5 +80,5 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
         if not interpret else None,
-        interpret=interpret,
+        interpret=check_interpret(interpret),
     )(x, w_gate, w_up, w_down)

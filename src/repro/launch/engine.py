@@ -454,13 +454,16 @@ class PagedKVPool:
 
     def __init__(self, names: Sequence[str], types: Sequence, *,
                  slots: int, page_size: int, max_pages: int,
-                 arena_bytes: int = 0):
-        import jax.numpy as jnp
-
+                 arena_bytes: int = 0,
+                 shardings: Optional[Sequence] = None):
         self.names = list(names)
         self.types = list(types)
-        self.buffers = [jnp.zeros(t.shape, np.dtype(t.dtype))
-                        for t in self.types]
+        # where each buffer lives (None = the default device); a tp
+        # engine passes the mesh shardings so every device allocates
+        # only its KV-head shard
+        self.shardings = (list(shardings) if shardings is not None
+                          else [None] * len(self.types))
+        self.reset_buffers()
         self.n_pages = self.types[0].shape[1]     # (L, P, Hkv, ps, D)
         self.page_size = int(page_size)
         self.max_pages = int(max_pages)
@@ -854,8 +857,8 @@ class PagedKVPool:
         """Fresh zero buffers (see :meth:`KVCachePool.reset_buffers`:
         a raised dispatch may have consumed the donated inputs)."""
         import jax.numpy as jnp
-        self.buffers = [jnp.zeros(t.shape, np.dtype(t.dtype))
-                        for t in self.types]
+        self.buffers = [jnp.zeros(t.shape, np.dtype(t.dtype), device=s)
+                        for t, s in zip(self.types, self.shardings)]
 
     def rebuild(self) -> None:
         """Reset to the empty state, reconciling counters (frees/
@@ -962,8 +965,9 @@ class ServeEngine:
         the paged chunk + prefill graphs over a ``tp``-device mesh via
         ``CompileOptions(mode="shardmap", partition="tp")``: each device
         holds ``n_kv_heads/tp`` heads of every KV page, page tables stay
-        replicated host-side, and greedy decode is token-identical to
-        ``tp=1``."""
+        replicated host-side, and greedy decode matches ``tp=1`` except
+        where a stream parts at a near-tie of the logits (see
+        :class:`repro.backend.sharding.PartitionProfile`)."""
         if config is None:
             config = EngineConfig(**legacy_kw)
         elif legacy_kw:
@@ -1089,9 +1093,15 @@ class ServeEngine:
                    if mode != "donated" else None)
         self.params = b.init_params(self.seed)
         self.param_order = [self.params[n] for n in b.param_names()]
+        # tp: every input placed once where the partitioned chunk reads
+        # it (the prefill chunks cut the same weights and KV the same way)
+        place = {}
+        if self.tp > 1:
+            from ..backend.sharding import input_shardings
+            place = input_shardings(self.cf.function, self.options)
         if mode != "lockstep":
-            import jax.numpy as jnp
-            self._jparam_map = {n: jnp.asarray(v)
+            import jax
+            self._jparam_map = {n: jax.device_put(v, place.get(n))
                                 for n, v in self.params.items()}
             self.jparams = [self._jparam_map[n] for n in b.param_names()]
 
@@ -1111,7 +1121,9 @@ class ServeEngine:
                     [n.out_types[0] for n in cache_nodes],
                     slots=self.slots, page_size=self.page_size,
                     max_pages=self.graphs.aux["max_pages"],
-                    arena_bytes=self.cf.memory_plan.arena_bytes)
+                    arena_bytes=self.cf.memory_plan.arena_bytes,
+                    shardings=([place[n.name] for n in cache_nodes]
+                               if place else None))
                 self._temp = np.zeros((self.slots,), np.float32)
                 self._topk = np.zeros((self.slots,), np.int32)
                 self._key = np.zeros((self.slots,), np.int32)

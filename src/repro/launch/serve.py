@@ -72,8 +72,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--tp", type=int, default=1,
                     help="paged mode: tensor-parallel width — shard the "
                          "chunk/prefill graphs and the KV pool's kv_heads "
-                         "dim over a tp-device mesh (greedy outputs stay "
-                         "token-identical to tp=1; on CPU export "
+                         "dim over a tp-device mesh (greedy outputs match "
+                         "tp=1 up to near-ties; on CPU export "
                          "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N first)")
     ap.add_argument("--no-prefix-sharing", action="store_true",
@@ -135,6 +135,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="--serve-http: request bodies beyond this get 413")
     args = ap.parse_args(argv)
 
+    from ..runtime.jax_cache import use_jax_compile_cache
+    use_jax_compile_cache()
     if args.faults is not None:
         from .faults import configure
         configure(args.faults, args.faults_seed)
@@ -321,6 +323,19 @@ def main(argv: Optional[List[str]] = None) -> int:
                 f"records were not reused")
         print(f"[disk-cache] ok ({st.disk_hits} hits, "
               f"{st.autotune_sweeps} sweeps)")
+    return _exit_code(engine)
+
+
+def _exit_code(engine) -> int:
+    """1 when a request failed or a dispatch raised while no fault was
+    injected on purpose: the engine contains such failures to keep
+    serving, so the exit code is where a broken device path shows."""
+    failed = engine.counters["failed"]
+    errors = engine.counters["engine_errors"]
+    if (failed or errors) and not engine.faults.rules:
+        print(f"[serve] ERROR: {failed} request(s) failed, {errors} "
+              f"engine error(s) with no fault injected", flush=True)
+        return 1
     return 0
 
 
@@ -365,7 +380,7 @@ def _serve_http(engine, args, cfg, mode, max_len) -> int:
         print("[serve-http] ERROR: drain left engine state behind "
               "(see report)", flush=True)
         return 1
-    return 0
+    return _exit_code(engine)
 
 
 if __name__ == "__main__":

@@ -47,6 +47,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     import jax
 
+    from ..runtime.jax_cache import use_jax_compile_cache
+    use_jax_compile_cache()
     from ..backend import Backend, CompileOptions
     from ..configs import get_config
     from ..configs.base import ShapeConfig

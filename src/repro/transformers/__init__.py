@@ -2,13 +2,4 @@
 from .base import (Executable, Transformer, available_transformers,  # noqa: F401
                    get_transformer, register_transformer)
 from . import interpreter as _interp  # noqa: F401  (registers itself)
-
-
-def _lazy_register_jax():
-    from . import jax_backend  # noqa: F401
-
-
-try:  # jax backend registers on import; keep interpreter usable without jax
-    _lazy_register_jax()
-except ImportError:  # pragma: no cover
-    pass
+from . import jax_backend as _jax  # noqa: F401  (registers itself)

@@ -26,6 +26,7 @@ from jax import lax
 from ..core.function import Function
 from ..core.node import Node
 from ..core.types import as_dtype, is_float
+from ..kernels import ops as kops
 from .base import Transformer, register_transformer
 
 EMIT: Dict[str, Callable] = {}
@@ -40,7 +41,7 @@ def _em(op: str):
 
 class EmitCtx:
     def __init__(self, mode: str = "jit", mesh=None, use_pallas: bool = False,
-                 remat_scan: bool = False, interpret_pallas: bool = True,
+                 remat_scan: bool = False, interpret_pallas: bool = False,
                  attn_impl: str = "auto", attn_chunk: int = 1024,
                  mm_bm: int = 256, mm_bn: int = 256, mm_bk: int = 512,
                  axis_rules=None):
@@ -243,10 +244,8 @@ def _(node, args, ctx):
             dn == (((a.ndim - 1,), (0,)), ((), ())) and \
             np.dtype(a.dtype) == np.dtype(b.dtype) == t.dtype and \
             is_float(np.dtype(a.dtype)):
-        kops = _pallas_ops()
         rows = a.size // a.shape[-1]
-        if kops is not None and \
-                kops.matmul_supported(rows, a.shape[-1], b.shape[1]):
+        if kops.matmul_supported(rows, a.shape[-1], b.shape[1]):
             out = kops.matmul(a.reshape(rows, a.shape[-1]), b,
                               bm=ctx.mm_bm, bn=ctx.mm_bn, bk=ctx.mm_bk,
                               interpret=ctx.interpret_pallas)
@@ -279,14 +278,6 @@ def _(node, args, ctx):
 
 
 # -- compounds (kernel-selection point) --------------------------------------
-def _pallas_ops():
-    try:
-        from ..kernels import ops as kops
-        return kops
-    except Exception:  # pragma: no cover
-        return None
-
-
 @_em("Softmax")
 def _(node, args, ctx):
     return [_outcast(node, jax.nn.softmax(_f32up(args[0]), axis=node.attrs["axis"]))]
@@ -299,8 +290,7 @@ def _(node, args, ctx):
 
 @_em("RMSNorm")
 def _(node, args, ctx):
-    kops = _pallas_ops() if ctx.use_pallas else None
-    if kops is not None and kops.rmsnorm_supported(args[0].shape):
+    if ctx.use_pallas and kops.rmsnorm_supported(args[0].shape):
         return [_outcast(node, kops.rmsnorm(args[0], args[1], node.attrs["eps"],
                                             interpret=ctx.interpret_pallas))]
     x = _f32up(args[0])
@@ -323,10 +313,10 @@ def _(node, args, ctx):
     t = node.out_types[0]
     d = x.shape[-1]
     rows = x.size // d
-    kops = _pallas_ops() if ctx.use_pallas else None
-    if kops is not None and \
+    if ctx.use_pallas and \
             np.dtype(x.dtype) == np.dtype(wg.dtype) == np.dtype(wd.dtype) and \
-            kops.swiglu_supported(rows, d, wg.shape[1], wd.shape[1]):
+            kops.swiglu_supported(rows, d, wg.shape[1], wd.shape[1],
+                                  np.dtype(x.dtype).itemsize):
         out = kops.swiglu(x.reshape(rows, d), wg, wu, wd,
                           bm=ctx.mm_bm, bn=ctx.mm_bn, bk=ctx.mm_bk,
                           interpret=ctx.interpret_pallas)
@@ -344,8 +334,7 @@ def _(node, args, ctx):
     t = node.out_types[0]
     d = x.shape[-1]
     rows = x.size // d
-    kops = _pallas_ops() if ctx.use_pallas else None
-    if kops is not None and np.dtype(x.dtype) == np.dtype(w.dtype) and \
+    if ctx.use_pallas and np.dtype(x.dtype) == np.dtype(w.dtype) and \
             kops.norm_matmul_supported(rows, d, w.shape[1]):
         out = kops.norm_matmul(x.reshape(rows, d), g, w,
                                eps=node.attrs["eps"], bm=ctx.mm_bm,
@@ -363,12 +352,11 @@ def _(node, args, ctx):
     x, wq, wk, wv, cos, sin = args
     at = node.attrs
     B, S, D = x.shape
-    kops = _pallas_ops() if ctx.use_pallas else None
 
     def mm(a2, w):
         # projections route through the Pallas tiled matmul; the rope
         # epilogue is elementwise and stays in XLA
-        if kops is not None and np.dtype(x.dtype) == np.dtype(w.dtype) and \
+        if ctx.use_pallas and np.dtype(x.dtype) == np.dtype(w.dtype) and \
                 kops.matmul_supported(B * S, D, w.shape[1]):
             return kops.matmul(a2, w, bm=ctx.mm_bm, bn=ctx.mm_bn,
                                bk=ctx.mm_bk, interpret=ctx.interpret_pallas)
@@ -419,8 +407,7 @@ def _(node, args, ctx):
     at = node.attrs
     q, k, v = args[:3]
     q_offset = args[3] if at["has_offset"] else None
-    kops = _pallas_ops() if ctx.use_pallas else None
-    if kops is not None and kops.attention_supported(q.shape, k.shape):
+    if ctx.use_pallas and kops.attention_supported(q.shape, k.shape):
         return [_outcast(node, kops.flash_attention(
             q, k, v, causal=at["causal"], window=at["window"], scale=at["scale"],
             q_offset=q_offset, interpret=ctx.interpret_pallas))]

@@ -89,6 +89,29 @@ def test_dot_general_und_einsum():
          RNG.normal(size=(2, 4, 5)).astype(np.float32))
 
 
+@pytest.mark.parametrize("a_shape,b_shape,contracting,batch,spec", [
+    ((3, 4), (4, 5), ((1,), (0,)), ((), ()), "ij,jk->ik"),
+    ((4, 3), (5, 4), ((0,), (1,)), ((), ()), "ji,kj->ik"),
+    ((3, 2, 4), (4, 2, 5), ((2,), (0,)), ((1,), (1,)), "ibj,jbk->bik"),
+    ((2, 3, 4, 6), (4, 6, 5), ((2, 3), (0, 1)), ((), ()),
+     "abjl,jlk->abk"),
+    ((3,), (4,), ((), ()), ((), ()), "i,k->ik"),
+    ((6,), (6,), ((0,), (0,)), ((), ()), "j,j->"),
+])
+def test_dot_general_layouts(a_shape, b_shape, contracting, batch, spec):
+    """The interpreter's DotGeneral (one batched matmul) against einsum:
+    batch dims anywhere, several or no contracting dims, scalar out."""
+    a = _p(a_shape, name="a")
+    b = _p(b_shape, name="b")
+    fn = Function([a, b], [ops.dot_general(a.out(), b.out(), contracting,
+                                           batch)])
+    x = RNG.normal(size=a_shape).astype(np.float32)
+    y = RNG.normal(size=b_shape).astype(np.float32)
+    out = both(fn, x, y)[0]
+    np.testing.assert_allclose(out, np.einsum(spec, x, y), rtol=1e-5,
+                               atol=1e-5)
+
+
 def test_gather_scatter_dynamic():
     x = _p((6, 3), name="x")
     idx = _p((4,), "i32", name="idx")

@@ -14,7 +14,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.core import ops
     from repro.core.function import Function
@@ -37,7 +37,7 @@ SCRIPT = textwrap.dedent("""
                         in_specs=P("data", None),
                         out_specs=(P(None, None), P(None, None),
                                    P("data", None), P("data", None)),
-                        check_rep=False)
+                        check_vma=False)
     arr = np.arange(128, dtype=np.float32).reshape(32, 4)
     shards = arr.reshape(4, 8, 4)
     group_sum = shards.sum(axis=0)          # (8, 4)

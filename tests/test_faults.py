@@ -228,6 +228,31 @@ def test_dispatch_failure_contained_and_engine_degraded():
                             "engine_errors": 1}
 
 
+@pytest.mark.parametrize("injected", [True, False])
+def test_serve_cli_exit_code_reports_contained_failures(injected):
+    """Containment keeps the engine serving, so the CLI's exit code is
+    where a real dispatch failure shows: non-zero unless it was an
+    injected fault."""
+    from repro.launch.serve import _exit_code
+
+    rng = np.random.default_rng(9)
+    if injected:
+        eng = _paged(faults=FaultInjector("dispatch.raise=after:1"))
+    else:
+        eng = _paged(faults=FaultInjector())
+
+        class _Broken:
+            def raw(self, *args):
+                raise RuntimeError("device lost")
+
+        eng.cf = _Broken()
+    ra = eng.submit(_prompt(rng, 4), 6)
+    rep = eng.run()
+    assert rep.statuses[ra] == "failed"
+    assert rep.counters["engine_errors"] == 1
+    assert _exit_code(eng) == (0 if injected else 1)
+
+
 def test_lockstep_dispatch_failure_contained():
     rng = np.random.default_rng(8)
     eng = ServeEngine(CFG, slots=2, max_len=12, mode="lockstep", seed=0,

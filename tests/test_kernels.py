@@ -206,5 +206,20 @@ def test_fused_kernel_selection_predicates():
     assert kops.swiglu_supported(128, 256, 512, 256)
     assert not kops.swiglu_supported(7, 256, 512, 256)    # rows not 8-aligned
     assert not kops.swiglu_supported(128, 100, 512, 256)  # D not lane-aligned
+    # a row block this wide cannot fit scoped VMEM at any tile: refused,
+    # so emission takes the XLA path by rule
+    assert not kops.swiglu_supported(8, 65536, 128, 128)
     assert kops.norm_matmul_supported(8, 128, 384)
     assert not kops.norm_matmul_supported(8, 384, 100)
+
+
+def test_interpret_mode_is_refused_on_a_tpu(monkeypatch):
+    """On a TPU a kernel runs compiled or not at all; interpret mode is
+    honoured only where no TPU is the default backend."""
+    import jax
+    from repro.kernels import check_interpret
+    assert check_interpret(True) is True          # CPU: asked for by name
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert check_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret mode"):
+        check_interpret(True)

@@ -161,3 +161,31 @@ def test_arch_profiles():
     spec3 = v3.spec_for(ParamInfo("w", (7168, 18432), None,
                                   ("embed", "ffn")), mesh)
     assert spec3[0] == ("pod", "data")
+
+
+# -- JAX compile cache placement -------------------------------------------------
+@pytest.mark.parametrize("from_env", [True, False])
+def test_jax_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    without it the cache is one fixed, git-ignored directory of the
+    checkout, never a temporary or per-process name."""
+    import jax
+
+    from repro.runtime import jax_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv(jax_cache.ENV, str(tmp_path))
+            assert jax_cache.use_jax_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            monkeypatch.delenv(jax_cache.ENV, raising=False)
+            got = jax_cache.use_jax_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            with open(os.path.join(repo, ".gitignore")) as fh:
+                assert ".jax_cache/" in fh.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

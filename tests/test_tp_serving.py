@@ -4,9 +4,10 @@ the paged KV pool over KV heads via the partition pass + shard_map.
 The contract under test, on a 2-device CPU mesh (subprocess, so the
 main test process keeps its single-device view):
 
-  * greedy decode is token-for-token identical to ``tp=1`` — the exact
-    column-parallel profile never splits a contraction, so every
-    arithmetic op computes the single-device values;
+  * greedy decode of these short requests is token-for-token identical
+    to ``tp=1`` — the column-parallel profile never splits a
+    contraction (longer streams may part at a near-tie, where the
+    per-device programs round differently: ``chip_smoke.py --tp``);
   * each device holds ``n_kv_heads/tp`` heads of every page:
     ``EngineReport.kv_bytes_per_device`` is exactly half the global
     pool bytes, and the partition stats show the inserted AllGathers
@@ -61,6 +62,15 @@ SCRIPT = textwrap.dedent("""
     assert st.get("all_gather", 0) >= 1
     assert st.get("all_reduce", 0) == 0, "exact profile: no split sums"
     assert e2.live_stats().get("tp") == 2
+    # placed once with the partition pass's shardings: every device holds
+    # its own half of each KV buffer and of each column-sharded weight
+    for buf in e2.pool.buffers:
+        shards = buf.addressable_shards
+        assert len({s.device for s in shards}) == 2
+        assert all(s.data.nbytes * 2 == buf.nbytes for s in shards)
+    wq = e2._jparam_map["layers/attn_wq"]
+    assert wq.committed and "model" in tuple(wq.sharding.spec)
+    assert all(s.data.nbytes * 2 == wq.nbytes for s in wq.addressable_shards)
     print("TP-PARITY-OK")
 
     # -- 2) prefix sharing / COW: host accounting oblivious to tp -------
@@ -125,3 +135,24 @@ def test_tp2_serving_parity_prefix_cancel():
     assert "TP-PARITY-OK" in out, proc.stderr[-4000:]
     assert "TP-PREFIX-OK" in out, proc.stderr[-4000:]
     assert "TP-CANCEL-OK" in out, proc.stderr[-4000:]
+
+
+def test_partition_bench_spawns_no_jax_child_on_an_accelerator(monkeypatch):
+    """E17's forced-CPU child runs only on a CPU host: a parent that holds
+    a chip refuses instead of starting a child that cannot reach it."""
+    import importlib.util
+    import os
+
+    import jax
+    import pytest
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "run.py")
+    spec = importlib.util.spec_from_file_location("bench_run", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "run", lambda *a, **k: pytest.fail(
+        "started a JAX child from a process that holds the chip"))
+    with pytest.raises(RuntimeError, match="chip_smoke.py --tp 4"):
+        bench.bench_partition()

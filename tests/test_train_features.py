@@ -105,7 +105,7 @@ def test_shardmap_dp_with_grad_compression():
         import numpy as np
         import jax
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import ops
         from repro.core.autodiff import GradBuilder
         from repro.core.function import Function
@@ -130,7 +130,7 @@ def test_shardmap_dp_with_grad_compression():
         mesh = jax.make_mesh((8,), ("data",))
         f = shard_map(lambda a, b: tuple(run(a, b)), mesh=mesh,
                       in_specs=(P("data", None), P(None, None)),
-                      out_specs=(P(), P(None, None)), check_rep=False)
+                      out_specs=(P(), P(None, None)), check_vma=False)
         rng = np.random.default_rng(0)
         X = rng.normal(size=(32, 8)).astype(np.float32)
         W = rng.normal(size=(8, 8)).astype(np.float32)

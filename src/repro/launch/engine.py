@@ -43,6 +43,7 @@ Donation invariants (see ROADMAP "Serving engine (PR 2)"):
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import time
@@ -54,6 +55,7 @@ from ..backend import Backend, CompileOptions
 from ..configs.base import ModelConfig, ShapeConfig
 from ..models.lm import ModelGraphs, build_graphs
 from .faults import FaultInjector, get_injector
+from .tracing import Tracer, get_tracer
 
 MODES = ("lockstep", "donated", "continuous", "paged")
 # request terminal statuses — every request ends in exactly one; each is
@@ -70,6 +72,9 @@ HEALTH_STATES = ("ok", "degraded", "halted")
 _STEP_INPUTS = ("token", "pos")
 _PAGED_STEP_INPUTS = _STEP_INPUTS + ("page_tbl", "temperature", "top_k",
                                      "key")
+# per-token latency samples kept for EngineReport's p50/p95: the newest
+# this many (a serving process runs for as long as it is up)
+LAT_SAMPLES = 1 << 14
 
 
 @dataclasses.dataclass(frozen=True)
@@ -949,7 +954,8 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig,
                  config: Optional[EngineConfig] = None, *,
                  options: Optional[CompileOptions] = None,
-                 faults: Optional[FaultInjector] = None, **legacy_kw):
+                 faults: Optional[FaultInjector] = None,
+                 tracer: Optional[Tracer] = None, **legacy_kw):
         """``ServeEngine(cfg, EngineConfig(...))`` is the sanctioned
         construction path; the legacy keyword spelling
         (``ServeEngine(cfg, mode=..., slots=...)``) still works and is
@@ -967,7 +973,9 @@ class ServeEngine:
         holds ``n_kv_heads/tp`` heads of every KV page, page tables stay
         replicated host-side, and greedy decode matches ``tp=1`` except
         where a stream parts at a near-tie of the logits (see
-        :class:`repro.backend.sharding.PartitionProfile`)."""
+        :class:`repro.backend.sharding.PartitionProfile`).  The paged
+        step records its host phases as spans into ``tracer``
+        (process-global by default, :mod:`repro.launch.tracing`)."""
         if config is None:
             config = EngineConfig(**legacy_kw)
         elif legacy_kw:
@@ -1138,13 +1146,17 @@ class ServeEngine:
         # tests pass their own), health state, lifecycle counters, and
         # the terminal-event feed a front door drains after each step
         self.faults = faults if faults is not None else get_injector()
+        self.tracer = tracer if tracer is not None else get_tracer()
         self.health = "ok"
         self.counters: Dict[str, int] = dict.fromkeys(
             TERMINAL_STATUSES + ("engine_errors",), 0)
         self._events: List[Tuple[int, str, Optional[str]]] = []
         self._steps = 0
-        self.step_seconds: List[float] = []   # decode dispatch durations
-        self.lat_ms: List[float] = []         # per-token latency samples
+        # decode dispatches: their summed duration (self._steps counts
+        # them) and the newest per-token latency samples
+        self.decode_seconds = 0.0
+        self.lat_ms: "collections.deque[float]" = collections.deque(
+            maxlen=LAT_SAMPLES)
         self._decode_tokens = 0
         self.prefill_seconds = 0.0
         self.late_admissions = 0
@@ -1566,45 +1578,50 @@ class ServeEngine:
         returned last-row logits, publish the prefix pages, and hand the
         row over to decode — returning the first token.  Returns None
         while the prompt is still partially cached (or after a contained
-        dispatch failure)."""
-        t0 = time.perf_counter()
-        P = len(req.prompt)
-        lo = req.prefill_pos
-        hi = min(lo + self.prefill_chunk, P)
-        g, cf, pvals, recycle = self._paged_prefill_for(hi - lo)
-        self.pool.ensure_pages(slot, hi - 1)
-        self.pool.prepare_writes(slot, lo, hi - 1)
-        tok_chunk = np.ascontiguousarray(
-            req.prompt[lo:hi].reshape(1, hi - lo))
-        ptbl = np.ascontiguousarray(self.pool.page_table[slot:slot + 1])
+        dispatch failure).  ``prefill_seconds`` gains the chunk's time
+        from the start of ``prefill.prep`` to the end of ``prefill.sync``."""
+        tr, rid = self.tracer, req.rid
+        with tr.span("prefill.prep", rid) as prep:
+            P = len(req.prompt)
+            lo = req.prefill_pos
+            hi = min(lo + self.prefill_chunk, P)
+            g, cf, pvals, recycle = self._paged_prefill_for(hi - lo)
+            self.pool.ensure_pages(slot, hi - 1)
+            self.pool.prepare_writes(slot, lo, hi - 1)
+            tok_chunk = np.ascontiguousarray(
+                req.prompt[lo:hi].reshape(1, hi - lo))
+            ptbl = np.ascontiguousarray(self.pool.page_table[slot:slot + 1])
         try:
-            self.faults.delay("dispatch.delay")
-            self.faults.check("prefill.raise")
-            outs = cf.raw(tok_chunk, np.int32(lo), ptbl,
-                          *self.pool.buffers, *pvals)
-            logits = np.asarray(outs[0])  # (1, 1, V) — syncs the chain
-            self.pool.update([self.pool.buffers[k] if j is None
-                              else outs[1 + j]
-                              for k, j in enumerate(recycle)])
+            with tr.span("prefill.dispatch", rid):
+                self.faults.delay("dispatch.delay")
+                self.faults.check("prefill.raise")
+                outs = cf.raw(tok_chunk, np.int32(lo), ptbl,
+                              *self.pool.buffers, *pvals)
+            with tr.span("prefill.sync", rid) as sync:
+                logits = np.asarray(outs[0])  # (1, 1, V) — syncs the chain
+                self.pool.update([self.pool.buffers[k] if j is None
+                                  else outs[1 + j]
+                                  for k, j in enumerate(recycle)])
         except Exception as exc:
             self._contain_step_failure(exc)
             return None
+        self.prefill_seconds += (sync.span.end_ns - prep.span.start_ns) / 1e9
         req.prefill_pos = hi
         self.pool.note_used(slot, hi)
-        self.prefill_seconds += time.perf_counter() - t0
         if hi < P:
             return None
-        first = _host_sample(logits, req.temperature, req.top_k, req.key,
-                             P - 1)
-        if self.prefix_sharing:
-            self.pool.publish_prefix(slot, req.prompt)
-        req.prefill_pos = None
-        req.pos = P
-        req.tokens = [first]
-        req.t_first = time.perf_counter()
-        self._tok[slot, 0] = first
-        self._pos[slot] = P
-        self.pool.note_used(slot, P)
+        with tr.span("prefill.sample", rid):
+            first = _host_sample(logits, req.temperature, req.top_k,
+                                 req.key, P - 1)
+            if self.prefix_sharing:
+                self.pool.publish_prefix(slot, req.prompt)
+            req.prefill_pos = None
+            req.pos = P
+            req.tokens = [first]
+            req.t_first = time.perf_counter()
+            self._tok[slot, 0] = first
+            self._pos[slot] = P
+            self.pool.note_used(slot, P)
         return first
 
     def step(self) -> List[Tuple[int, int]]:
@@ -1659,7 +1676,7 @@ class ServeEngine:
             return emitted
         dt = time.perf_counter() - t0
         self._steps += 1
-        self.step_seconds.append(dt)
+        self.decode_seconds += dt
         self._decode_tokens += len(active)
         self.lat_ms.extend([dt * 1e3] * len(active))
         for slot, req in active:
@@ -1676,13 +1693,88 @@ class ServeEngine:
     # -- paged chunked scheduling --------------------------------------------
     def _step_paged(self) -> List[Tuple[int, int]]:
         """One chunk: admit what fits (chunk boundary = the only
-        admission/retirement point), grow pages to cover the chunk's
-        writes, then one fused ``chunk_steps``-token dispatch."""
-        if self._t0_work is None:
-            self._t0_work = time.perf_counter()
-        self._reap()
-        K = self.chunk_steps
-        emitted: List[Tuple[int, int]] = []
+        admission/retirement point), advance each prefilling slot by one
+        prefill chunk, grow pages to cover the decode chunk's writes,
+        then one fused ``chunk_steps``-token dispatch.  Each host phase
+        is a span of :attr:`tracer` (``sched.*``, ``prefill.*``,
+        ``decode.*``) under the step's ``sched.step``; the decode
+        dispatch's duration is read from its spans' clock."""
+        tr = self.tracer
+        with tr.span("sched.step"):
+            if self._t0_work is None:
+                self._t0_work = time.perf_counter()
+            with tr.span("sched.reap"):
+                self._reap()
+            emitted: List[Tuple[int, int]] = []
+            with tr.span("sched.admit"):
+                self._admit_paged(emitted)
+            # advance chunked prefills — one chunk per prefilling slot per
+            # step, so long prompts share the step loop with decode rows
+            # instead of stalling them behind one dense prefill dispatch
+            for slot, rid in enumerate(list(self._slot_req)):
+                if rid is None:
+                    continue
+                req = self._requests[rid]
+                if req.finished or req.prefill_pos is None:
+                    continue
+                tok = self._prefill_chunk_step(slot, req)
+                if tok is not None:
+                    emitted.append((req.rid, tok))
+                    if req.done:  # max_new == 1: done at prefill completion
+                        self._finish(req)
+            prefilling = [s for s, rid in enumerate(self._slot_req)
+                          if rid is not None
+                          and self._requests[rid].prefill_pos is not None]
+            active = [(s, self._requests[rid])
+                      for s, rid in enumerate(self._slot_req)
+                      if rid is not None and s not in prefilling]
+            if not active:
+                return emitted
+            with tr.span("decode.prep"):
+                dispatch_tbl = self._prep_decode(active, prefilling)
+            try:
+                with tr.span("decode.dispatch") as dispatch:
+                    self.faults.delay("dispatch.delay")
+                    self.faults.check("dispatch.raise")
+                    outs = self.cf.raw(self._tok, self._pos, dispatch_tbl,
+                                       self._temp, self._topk, self._key,
+                                       *self.pool.buffers, *self.jparams)
+                with tr.span("decode.sync") as sync:
+                    toks = np.asarray(outs[0])  # (K, B, 1) — syncs the chain
+                    self.pool.update([self.pool.buffers[k] if j is None
+                                      else outs[1 + j]
+                                      for k, j in enumerate(self._recycle)])
+            except Exception as exc:
+                self._contain_step_failure(exc)
+                return emitted
+            dt = (sync.span.end_ns - dispatch.span.start_ns) / 1e9
+            self._steps += 1
+            self.decode_seconds += dt
+            with tr.span("sched.retire"):
+                K = self.chunk_steps
+                chunk_tokens = 0
+                for slot, req in active:
+                    take = min(req.max_new - len(req.tokens), K)
+                    for t in toks[:take, slot, 0]:
+                        req.tokens.append(int(t))
+                        emitted.append((req.rid, int(t)))
+                    req.pos += take
+                    self.pool.note_used(slot, req.pos)
+                    chunk_tokens += take
+                    if req.done:
+                        self._finish(req)
+                self._decode_tokens += chunk_tokens
+                # like donated mode, a chunk's tokens become visible when
+                # the dispatch returns: the honest per-token latency
+                # sample is the chunk duration (chunking trades
+                # time-to-token for throughput)
+                self.lat_ms.extend([dt * 1e3] * chunk_tokens)
+            return emitted
+
+    def _admit_paged(self, emitted: List[Tuple[int, int]]) -> None:
+        """Admit queued requests, head first, while the pool can hold
+        them; the dense-prefill path (``prefill_chunk=0``) appends each
+        admitted request's first token to ``emitted``."""
         while self._queue:
             req = self._requests[self._queue[0]]
             if self.prefix_sharing and self.prefill_chunk and \
@@ -1709,28 +1801,11 @@ class ServeEngine:
                 emitted.append((req.rid, self._admit(req, slot)))
                 if req.done:  # max_new == 1: done straight out of prefill
                     self._finish(req)
-        # advance chunked prefills — one chunk per prefilling slot per
-        # step, so long prompts share the step loop with decode rows
-        # instead of stalling them behind one dense prefill dispatch
-        for slot, rid in enumerate(list(self._slot_req)):
-            if rid is None:
-                continue
-            req = self._requests[rid]
-            if req.finished or req.prefill_pos is None:
-                continue
-            tok = self._prefill_chunk_step(slot, req)
-            if tok is not None:
-                emitted.append((req.rid, tok))
-                if req.done:  # max_new == 1: done at prefill completion
-                    self._finish(req)
-        prefilling = [s for s, rid in enumerate(self._slot_req)
-                      if rid is not None
-                      and self._requests[rid].prefill_pos is not None]
-        active = [(s, self._requests[rid])
-                  for s, rid in enumerate(self._slot_req)
-                  if rid is not None and s not in prefilling]
-        if not active:
-            return emitted
+
+    def _prep_decode(self, active, prefilling: List[int]) -> np.ndarray:
+        """Fill the decode chunk's row arrays and return the page table
+        it dispatches with."""
+        K = self.chunk_steps
         for slot, req in active:
             # cover this chunk's writes, capped at the request's lifetime
             # (== its admission reservation); a row that finishes
@@ -1770,40 +1845,7 @@ class ServeEngine:
         self._kv_sample(self.pool.committed_pages * self.pool.bytes_per_page,
                         sum(r.pos for _, r in active) + prefill_rows)
         self.pool.sample_fragmentation()
-        t0 = time.perf_counter()
-        try:
-            self.faults.delay("dispatch.delay")
-            self.faults.check("dispatch.raise")
-            outs = self.cf.raw(self._tok, self._pos, dispatch_tbl,
-                               self._temp, self._topk, self._key,
-                               *self.pool.buffers, *self.jparams)
-            toks = np.asarray(outs[0])  # (K, B, 1) — syncs the chain
-            self.pool.update([self.pool.buffers[k] if j is None
-                              else outs[1 + j]
-                              for k, j in enumerate(self._recycle)])
-        except Exception as exc:
-            self._contain_step_failure(exc)
-            return emitted
-        dt = time.perf_counter() - t0
-        self._steps += 1
-        self.step_seconds.append(dt)
-        chunk_tokens = 0
-        for slot, req in active:
-            take = min(req.max_new - len(req.tokens), K)
-            for t in toks[:take, slot, 0]:
-                req.tokens.append(int(t))
-                emitted.append((req.rid, int(t)))
-            req.pos += take
-            self.pool.note_used(slot, req.pos)
-            chunk_tokens += take
-            if req.done:
-                self._finish(req)
-        self._decode_tokens += chunk_tokens
-        # like donated mode, a chunk's tokens become visible when the
-        # dispatch returns: the honest per-token latency sample is the
-        # chunk duration (chunking trades time-to-token for throughput)
-        self.lat_ms.extend([dt * 1e3] * chunk_tokens)
-        return emitted
+        return dispatch_tbl
 
     def _kv_sample(self, reserved_bytes: int, active_tokens: int) -> None:
         if active_tokens > 0:
@@ -1910,7 +1952,7 @@ class ServeEngine:
                 if r.done and not r.finished:
                     self._retire(r, "completed")
             self._steps += 1
-            self.step_seconds.append(dt)
+            self.decode_seconds += dt
             self._decode_tokens += emitted
             self.lat_ms.extend([dt * 1e3] * emitted)
             if all(r.done for r in reqs):
@@ -1926,7 +1968,7 @@ class ServeEngine:
         toks = np.asarray(outs[0])  # (steps, B, 1) — syncs the chain
         dt = time.perf_counter() - t0
         self._steps += 1
-        self.step_seconds.append(dt)
+        self.decode_seconds += dt
         # every token of the fused chunk becomes visible only when the
         # dispatch returns, so the honest per-token latency sample is the
         # whole chunk duration — donated mode trades time-to-token for
@@ -1993,13 +2035,13 @@ class ServeEngine:
         results = {rid: np.asarray(r.tokens, np.int32)
                    for rid, r in self._requests.items()}
         gen = sum(len(v) for v in results.values())
-        decode_secs = sum(self.step_seconds)
         ttft = [(r.t_first - r.t_submit) * 1e3
                 for r in self._requests.values() if r.t_first is not None]
         return EngineReport(
             mode=self.mode, results=results, wall_seconds=wall,
             generated_tokens=gen, tok_s=gen / max(wall, 1e-9),
-            decode_tok_s=self._decode_tokens / max(decode_secs, 1e-9),
+            decode_tok_s=self._decode_tokens / max(self.decode_seconds,
+                                                   1e-9),
             p50_ms=_percentile(self.lat_ms, 50),
             p95_ms=_percentile(self.lat_ms, 95),
             steps=self._steps, prefill_seconds=self.prefill_seconds,

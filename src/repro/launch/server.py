@@ -18,7 +18,9 @@ concurrent clients:
   * ``GET /v1/metrics`` — rolling server SLOs (TTFT p50/p95, inter-token
     p50/p95, sustained tok/s) from :class:`ServerStats` plus the
     engine's instantaneous gauges (queue depth, active slots,
-    pages_in_use) from ``ServeEngine.live_stats()``.
+    pages_in_use) from ``ServeEngine.live_stats()``, and under
+    ``engine.spans`` each host span's count, wall and CPU seconds since
+    start (:meth:`repro.launch.tracing.Tracer.totals`).
   * ``GET /healthz`` — liveness + drain state.
 
 Admission maps onto the engine's queue-aware ``can_admit``: a request
@@ -192,6 +194,8 @@ class ServeHTTPServer:
         self.max_body_bytes = int(max_body_bytes)
         self.heartbeat_s = float(heartbeat_s)
         self.stats = ServerStats()
+        # the engine thread's spans go where the engine's own go
+        self.tracer = engine.tracer
 
         # engine-thread state: _cv guards _pending/_cancels/_draining;
         # _live is touched only by the engine thread after submission
@@ -302,15 +306,24 @@ class ServeHTTPServer:
         return self.engine.queue_depth > 0 or (
             self.engine.pool is not None and self.engine.pool.active > 0)
 
+    def _nothing_to_do(self) -> bool:
+        """Caller holds ``_cv``."""
+        return not self._pending and not self._cancels \
+            and not self._engine_busy() and not self._draining
+
     def _engine_loop(self) -> None:
-        eng = self.engine
+        """Each pass: wait for work (``front.wait``), hand the engine the
+        pending requests and cancels (``front.intake``), run one engine
+        step and stream its tokens (``front.emit``), then close the
+        streams of finished requests (``front.terminal``)."""
+        eng, tr = self.engine, self.tracer
         try:
             while True:
                 with self._cv:
-                    while not self._pending and not self._cancels \
-                            and not self._engine_busy() \
-                            and not self._draining:
-                        self._cv.wait()
+                    if self._nothing_to_do():
+                        with tr.span("front.wait"):
+                            while self._nothing_to_do():
+                                self._cv.wait()
                     if self._draining and not self._pending \
                             and not self._cancels \
                             and not self._engine_busy():
@@ -319,29 +332,17 @@ class ServeHTTPServer:
                     self._pending.clear()
                     cancels = list(self._cancels)
                     self._cancels.clear()
-                for rid in cancels:
-                    eng.cancel(rid, "client disconnected")
-                for item in batch:
-                    deadline = item.deadline_s
-                    if deadline is not None:
-                        # the knob bounds the whole request, so charge
-                        # the time it already waited for this thread
-                        deadline = max(
-                            deadline - (time.perf_counter() - item.t_accept),
-                            1e-3)
-                    rid = eng.submit(item.prompt, item.max_new,
-                                     temperature=item.temperature,
-                                     top_k=item.top_k, key=item.key,
-                                     deadline_s=deadline)
-                    item.rid = rid
-                    self._live[rid] = item
-                    if item.cancelled:  # client left before submission
-                        eng.cancel(rid, "client disconnected")
+                if batch or cancels:
+                    with tr.span("front.intake"):
+                        self._intake(batch, cancels)
                 if self._engine_busy():
-                    for rid, tok in eng.step():
-                        self._emit(rid, tok)
-                for rid, status, error in eng.drain_events():
-                    self._on_terminal(rid, status, error)
+                    emitted = eng.step()
+                    with tr.span("front.emit"):
+                        for rid, tok in emitted:
+                            self._emit(rid, tok)
+                with tr.span("front.terminal"):
+                    for rid, status, error in eng.drain_events():
+                        self._on_terminal(rid, status, error)
         except BaseException as exc:  # fail loudly into every open stream
             self._engine_error = exc
             with self._cv:
@@ -352,6 +353,25 @@ class ServeHTTPServer:
             self._live.clear()
         finally:
             self._finalize()
+
+    def _intake(self, batch: List[_Stream], cancels: List[int]) -> None:
+        eng = self.engine
+        for rid in cancels:
+            eng.cancel(rid, "client disconnected")
+        for item in batch:
+            deadline = item.deadline_s
+            if deadline is not None:
+                # the knob bounds the whole request, so charge the time
+                # it already waited for this thread
+                deadline = max(
+                    deadline - (time.perf_counter() - item.t_accept), 1e-3)
+            rid = eng.submit(item.prompt, item.max_new,
+                             temperature=item.temperature, top_k=item.top_k,
+                             key=item.key, deadline_s=deadline)
+            item.rid = rid
+            self._live[rid] = item
+            if item.cancelled:  # client left before submission
+                eng.cancel(rid, "client disconnected")
 
     def _emit(self, rid: int, tok: int) -> None:
         item = self._live.get(rid)
@@ -497,9 +517,11 @@ class ServeHTTPServer:
                                           "health": health,
                                           "draining": self._draining}))
         elif path == "/v1/metrics" and method == "GET":
+            engine = self.engine.live_stats()
+            engine["spans"] = self.tracer.totals()
             doc = {
                 "server": self.stats.snapshot(),
-                "engine": self.engine.live_stats(),
+                "engine": engine,
                 "health": self.engine.health,
                 "wait_queue": len(self._pending) + self.engine.queue_depth,
                 "max_wait_queue": self.max_wait_queue,

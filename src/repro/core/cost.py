@@ -153,6 +153,12 @@ def _node_cost(node: Node, cost: Cost, mult: float, attn_impl: str) -> None:
               "CollectivePermute"):
         cost.add(op, 0.0, _io_bytes(node), mult)
         return
+    if op == "ScatterSet":
+        # in place: the rows are read and written, the operand is not
+        # moved (a carried pool stays put when nothing else reads it)
+        _op, idx, upd = node.inputs
+        cost.add(op, 0.0, float(idx.type.nbytes + 2 * upd.type.nbytes), mult)
+        return
     if op in _TRANS or op in _CHEAP:
         # producer-fusion model: elementwise ops fuse into chains, so
         # each op pays its output write only; reads happen once at the

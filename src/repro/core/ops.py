@@ -562,6 +562,37 @@ def scatter_add(operand: Value, indices: Value, updates: Value) -> Value:
     return Node("ScatterAdd", [operand, indices, updates], {}, [operand.type]).out()
 
 
+_register("ScatterSet")
+
+
+def scatter_set(operand: Value, indices: Value, updates: Value,
+                axes: Sequence[int]) -> Value:
+    """Write rows of ``updates`` into ``operand`` at index tuples.
+
+    indices: (N..., K) integer, one index tuple per row into the K
+    operand dims ``axes``; updates: (N..., W...)
+    where W is the operand's shape with the ``axes`` dims dropped, in
+    order.  ``out[..., indices[n, k] at axes[k], ...] = updates[n]``;
+    rows with an index outside ``[0, dim)`` (negative ones too) are
+    dropped.  Indices are not declared unique: where rows collide, one
+    of them wins, unspecified which.
+    """
+    if not is_int(indices.dtype):
+        raise TypeError("scatter indices must be integer")
+    K = indices.shape[-1] if indices.rank else 0
+    axes = tuple(int(a) % operand.rank for a in axes)
+    if len(axes) != K or len(set(axes)) != K or K == 0:
+        raise ValueError(f"scatter_set axes {axes} vs index width {K}")
+    window = tuple(s for d, s in enumerate(operand.shape) if d not in axes)
+    expected = indices.shape[:-1] + window
+    if updates.shape != expected:
+        raise ValueError(f"scatter updates {updates.shape} != {expected}")
+    if updates.dtype != operand.dtype:
+        raise TypeError("scatter_set dtype mismatch")
+    return Node("ScatterSet", [operand, indices, updates], {"axes": axes},
+                [operand.type]).out()
+
+
 _register("DynamicSlice")
 
 

@@ -401,6 +401,8 @@ class _Infer:
             return _Step([c0], cons)
         if op == "Attention":
             return self._attention_step(node, ins)
+        if op == "ScatterSet":
+            return self._scatter_set_step(node, ins)
         if op == "LinearRecurrence":
             ax = node.attrs["axis"]
             out, consumed, wishes = self._unify(ins, skip_dims={ax})
@@ -551,6 +553,26 @@ class _Infer:
         for i in range(3, len(ins)):            # q_offset stays replicated
             consumed[i] = [None] * len(ins[i])
         return _Step([out], consumed, wishes=wishes)
+
+    def _scatter_set_step(self, node: Node, ins: List[Spec]) -> _Step:
+        """Every device writes every row into its own slice of the
+        window: the operand's indexed dims, the indices and the rows'
+        own dims are replicated; the window dims unify with the rows'
+        trailing dims (a pool sharded on heads stays sharded)."""
+        axes = node.attrs["axes"]
+        nb = len(ins[1]) - 1
+        win = [d for d in range(len(ins[0])) if d not in axes]
+        out_w, (c_op, c_up), wishes = self._unify(
+            [tuple(ins[0][d] for d in win), tuple(ins[2][nb:])])
+        out = [None] * len(ins[0])
+        c0 = [None] * len(ins[0])
+        for i, d in enumerate(win):
+            out[d], c0[d] = out_w[i], c_op[i]
+        moved = [(0, {win[i]: a for i, a in add.items()}) if k == 0
+                 else (2, {nb + i: a for i, a in add.items()})
+                 for k, add in wishes]
+        return _Step([out], [c0, (None,) * len(ins[1]),
+                             [None] * nb + list(c_up)], wishes=moved)
 
     # -- Scan ---------------------------------------------------------------
     def _scan_key(self, node: Node, ins: List[Spec]):

@@ -336,78 +336,82 @@ def _rowpos_cached_attention(
 
 
 # -- paged KV cache (serve_paged) ----------------------------------------------
-def paged_gather(pool: Value, page_tbl: Value) -> Value:
-    """Gather a slot-major KV view out of a page pool.
+def paged_gather(pool: Value, page_tbl: Value, layer: Value) -> Value:
+    """Gather layer ``layer``'s slot-major KV view out of a page pool.
 
-    pool: (P, Hkv, ps, D) physical pages; page_tbl: (B, MP) i32 physical
-    page id per (row, logical page).  Returns (B, Hkv, MP*ps, D) where
-    index ``j`` along the seq axis is logical token position ``j`` — the
-    take-along-page-axis + reshape that makes paged attention identical
-    to attending a dense per-row cache (garbage beyond ``pos`` is masked
-    by the caller exactly like the dense path's unwritten rows).
+    pool: (L, P, Hkv, ps, D) physical pages of every layer; page_tbl:
+    (B, MP) i32 physical page id per (row, logical page); layer: () i32.
+    Returns (B, Hkv, MP*ps, D) where index ``j`` along the seq axis is
+    logical token position ``j`` — a take over the (L*P) page axis at
+    ``layer*P + page_tbl`` + reshape that makes paged attention
+    identical to attending a dense per-row cache (garbage beyond ``pos``
+    is masked by the caller exactly like the dense path's unwritten
+    rows).
     """
-    P, Hkv, ps, D = pool.shape
+    L, P, Hkv, ps, D = pool.shape
     B, MP = page_tbl.shape
-    g = ops.gather(pool, page_tbl, axis=0)           # (B, MP, Hkv, ps, D)
+    flat = ops.reshape(pool, (L * P, Hkv, ps, D))
+    base = ops.broadcast_to(layer * ops.constant(P, dtype="i32"), (B, MP))
+    g = ops.gather(flat, base + page_tbl, axis=0)    # (B, MP, Hkv, ps, D)
     g = ops.transpose(g, (0, 2, 1, 3, 4))            # (B, Hkv, MP, ps, D)
     return ops.reshape(g, (B, Hkv, MP * ps, D))
 
 
 def paged_write(pool: Value, new: Value, page_tbl: Value, pos: Value,
-                page_size: int) -> Value:
-    """Blend each row's new (B, Hkv, 1, D) k/v into its page slot.
+                page_size: int, layer: Value) -> Value:
+    """Scatter each row's new (B, Hkv, 1, D) k/v into its page slot.
 
-    Row ``b`` writes at physical page ``page_tbl[b, pos[b]//ps]``, offset
-    ``pos[b] % ps`` (a one-hot blend over the pool — pages are exclusive
-    to one row, so concurrent rows never collide; rows whose logical page
-    index overruns the table are clamped onto their last page-table entry,
-    which the engine points at the shared trash page for retired rows).
-    The written value is ``convert(new, pool.dtype)`` exactly — the same
+    pool: (L, P, Hkv, ps, D), the whole stacked pool the layer scan
+    carries.  Row ``b`` writes its (Hkv, D) row in place at
+    ``(layer, page_tbl[b, pos[b]//ps], :, pos[b] % ps, :)`` — one
+    :func:`~repro.core.ops.scatter_set`, so a step moves the rows it
+    writes and no other page.  Rows whose logical page index overruns
+    the table are clamped onto their last page-table entry, which the
+    engine points at the shared trash page for retired rows; such rows
+    may collide there, and whichever wins is never read as live.  The
+    written value is ``convert(new, pool.dtype)`` exactly — the same
     value the dense one-hot blend writes, which is what keeps paged and
     continuous decoding token-for-token identical.
     """
-    P, Hkv, ps, D = pool.shape
     B, MP = page_tbl.shape
+    Hkv, D = pool.shape[2], pool.shape[4]
     psc = ops.constant(page_size, dtype="i32")
     lp = pos / psc                       # logical page (int divide = floor)
     off = pos - lp * psc                 # offset within the page
     lp = ops.minimum(lp, ops.constant(MP - 1, dtype="i32"))
-    pid = ops.reshape(ops.take_along_last(page_tbl, ops.reshape(lp, (B, 1))),
-                      (B,))
-    page_oh = ops.one_hot(pid, P, dtype=pool.dtype)      # (B, P)
-    off_oh = ops.one_hot(off, ps, dtype=pool.dtype)      # (B, ps)
-    wmask = ops.einsum("bp,bs->bps", page_oh, off_oh)    # (B, P, ps)
-    newr = ops.reshape(ops.convert(new, pool.dtype), (B, Hkv, D))
-    upd = ops.einsum("bps,bhd->phsd", wmask, newr)       # (P, Hkv, ps, D)
-    hit = ops.reshape(ops.reduce_sum(wmask, axes=[0]), (P, 1, ps, 1))
-    cond = ops.greater(ops.broadcast_to(hit, pool.shape),
-                       ops.constant(0.0, dtype=pool.dtype))
-    return ops.select(cond, upd, pool)
+    pid = ops.take_along_last(page_tbl, ops.reshape(lp, (B, 1)))  # (B, 1)
+    lay = ops.broadcast_to(ops.reshape(layer, (1, 1)), (B, 1))
+    idx = ops.concat([lay, pid, ops.reshape(off, (B, 1))], axis=1)
+    rows = ops.reshape(ops.convert(new, pool.dtype), (B, Hkv, D))
+    return ops.scatter_set(pool, idx, rows, axes=(0, 1, 3))
 
 
 def paged_self_attention(
     b: ModelBuilder, x: Value, w: Dict[str, Value], *,
     prefix: str, n_heads: int, n_kv: int, d_head: int,
     rope: Tuple[Value, Value], pool_k: Value, pool_v: Value,
-    page_tbl: Value, pos: Value, page_size: int,
+    page_tbl: Value, pos: Value, page_size: int, layer: Value,
     window: Optional[int] = None, qkv_bias: bool = False,
 ) -> Tuple[Value, Tuple[Value, Value]]:
     """Single-token self attention through a paged KV pool.
 
-    pool_k/pool_v: (P, Hkv, ps, D) page pools; page_tbl: (B, MP) i32;
-    pos: (B,) i32 per-row positions (``rope`` must be the per-row tables
-    from :func:`rope_tables_rows`).  Writes each row's k/v into its page,
-    gathers the slot-major view back, and attends with the same masked
-    per-row math as the dense continuous path (token parity by
-    construction).  Returns (out (B,1,Dm), (new_pool_k, new_pool_v)).
+    pool_k/pool_v: (L, P, Hkv, ps, D) page pools of every layer, carried
+    by the layer scan; layer: () i32 this layer's index; page_tbl:
+    (B, MP) i32; pos: (B,) i32 per-row positions (``rope`` must be the
+    per-row tables from :func:`rope_tables_rows`).  Scatters each row's
+    k/v into its page slot in place (:func:`paged_write`), gathers the
+    layer's slot-major view back from the updated pools, and attends
+    with the same masked per-row math as the dense continuous path
+    (token parity by construction).  Returns (out (B,1,Dm),
+    (new_pool_k, new_pool_v)).
     """
     q, k, v = project_qkv(b, x, w, prefix, n_heads, n_kv, qkv_bias)
     q = apply_rope_rows(q, *rope)
     k = apply_rope_rows(k, *rope)
-    pool_k = paged_write(pool_k, k, page_tbl, pos, page_size)
-    pool_v = paged_write(pool_v, v, page_tbl, pos, page_size)
-    gk = paged_gather(pool_k, page_tbl)
-    gv = paged_gather(pool_v, page_tbl)
+    pool_k = paged_write(pool_k, k, page_tbl, pos, page_size, layer)
+    pool_v = paged_write(pool_v, v, page_tbl, pos, page_size, layer)
+    gk = paged_gather(pool_k, page_tbl, layer)
+    gv = paged_gather(pool_v, page_tbl, layer)
     B, Skv = pos.shape[0], gk.shape[2]
     kpos = ops.iota((B, Skv), 1, "i32")
     posb = ops.broadcast_to(ops.reshape(pos, (B, 1)), (B, Skv))
@@ -462,22 +466,24 @@ def paged_prefill_attention(
     b: ModelBuilder, x: Value, w: Dict[str, Value], *,
     prefix: str, n_heads: int, n_kv: int, d_head: int,
     rope: Tuple[Value, Value], pool_k: Value, pool_v: Value,
-    page_tbl: Value, pos0: Value, page_size: int,
+    page_tbl: Value, pos0: Value, page_size: int, layer: Value,
     window: Optional[int] = None, qkv_bias: bool = False,
 ) -> Tuple[Value, Tuple[Value, Value]]:
     """Chunked-prefill self attention through a paged KV pool.
 
     x: (1, C, Dm) — one request's prompt chunk at absolute positions
     ``pos0 .. pos0+C-1``; rope: the (C, half) tables built at offset
-    ``pos0``; pool_k/pool_v: (P, Hkv, ps, D) page pools; page_tbl: the
-    row's (1, MP) table.  All C rotated k/v rows are written straight
-    into the row's pages (the :func:`paged_write` one-hot blend, with
-    the chunk axis standing in for the batch axis — positions within a
-    chunk are distinct, so rows never collide), then the slot-major view
-    is gathered back and attended causally at absolute positions with
-    the same masked f32 math as the decode paths.  Earlier chunks' rows
-    (and COW-shared prefix pages) are already in the pool, so a long
-    prompt prefills chunk by chunk without a dense (1, P) cache.
+    ``pos0``; pool_k/pool_v: (L, P, Hkv, ps, D) page pools of every
+    layer, carried by the layer scan; layer: () i32; page_tbl: the row's
+    (1, MP) table.  All C rotated k/v rows are scattered in place into
+    the row's page slots of this layer (:func:`paged_write`, with the
+    chunk axis standing in for the batch axis — positions within a
+    chunk are distinct, so rows never collide), then the layer's
+    slot-major view is gathered back and attended causally at absolute
+    positions with the same masked f32 math as the decode paths.
+    Earlier chunks' rows (and COW-shared prefix pages) are already in
+    the pool, so a long prompt prefills chunk by chunk without a dense
+    (1, P) cache.
     Returns (out (1, C, Dm), (new_pool_k, new_pool_v)).
     """
     q, k, v = project_qkv(b, x, w, prefix, n_heads, n_kv, qkv_bias)
@@ -489,10 +495,12 @@ def paged_prefill_attention(
     ptbl_c = ops.broadcast_to(page_tbl, (Cq, MP))
     k_rows = ops.transpose(k, (2, 1, 0, 3))      # (C, Hkv, 1, D)
     v_rows = ops.transpose(v, (2, 1, 0, 3))
-    pool_k = paged_write(pool_k, k_rows, ptbl_c, positions, page_size)
-    pool_v = paged_write(pool_v, v_rows, ptbl_c, positions, page_size)
-    gk = paged_gather(pool_k, page_tbl)
-    gv = paged_gather(pool_v, page_tbl)
+    pool_k = paged_write(pool_k, k_rows, ptbl_c, positions, page_size,
+                         layer)
+    pool_v = paged_write(pool_v, v_rows, ptbl_c, positions, page_size,
+                         layer)
+    gk = paged_gather(pool_k, page_tbl, layer)
+    gv = paged_gather(pool_v, page_tbl, layer)
     Skv = gk.shape[2]
     kpos = ops.iota((1, Skv), 1, "i32")
     att = _chunkpos_attend(q, gk, gv, kpos, positions, n_heads=n_heads,

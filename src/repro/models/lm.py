@@ -122,21 +122,23 @@ def _dense_block(b, cfg, h, w, rope, *, window=None, cache=None, pos=None,
     if paged is not None and chunk:
         # chunked prefill: a (1, C) prompt slice written straight into the
         # page pool; pos is the scalar base position of the chunk
-        page_tbl, page_size = paged
+        page_tbl, page_size, layer = paged
         att, extras = C.paged_prefill_attention(
             b, xn, w, prefix="attn_", n_heads=cfg.n_heads,
             n_kv=cfg.n_kv_heads, d_head=dh, rope=rope, pool_k=cache[0],
             pool_v=cache[1], page_tbl=page_tbl, pos0=pos,
-            page_size=page_size, window=window, qkv_bias=cfg.qkv_bias)
+            page_size=page_size, layer=layer, window=window,
+            qkv_bias=cfg.qkv_bias)
     elif paged is not None:
-        # paged: cache is (pool_k, pool_v) page pools, paged is the
-        # (page_tbl, page_size) routing pair
-        page_tbl, page_size = paged
+        # paged: cache is the (pool_k, pool_v) page pools of every layer,
+        # paged is the (page_tbl, page_size, layer index) routing triple
+        page_tbl, page_size, layer = paged
         att, extras = C.paged_self_attention(
             b, xn, w, prefix="attn_", n_heads=cfg.n_heads,
             n_kv=cfg.n_kv_heads, d_head=dh, rope=rope, pool_k=cache[0],
             pool_v=cache[1], page_tbl=page_tbl, pos=pos,
-            page_size=page_size, window=window, qkv_bias=cfg.qkv_bias)
+            page_size=page_size, layer=layer, window=window,
+            qkv_bias=cfg.qkv_bias)
     else:
         att, extras = C.self_attention(
             b, xn, w, prefix="attn_", n_heads=cfg.n_heads,
@@ -149,6 +151,28 @@ def _dense_block(b, cfg, h, w, rope, *, window=None, cache=None, pos=None,
     xn2 = C.apply_norm(h, w, "ln2_", cfg.norm, cfg.norm_eps)
     h = h + C.apply_mlp(b, xn2, w, "mlp_", cfg.mlp)
     return h, extras
+
+
+def _layer_index(n_layers: int) -> Dict[str, Value]:
+    """The layer scan's xs entry that hands each layer its own index."""
+    return {"layer": ops.iota((n_layers,), 0, "i32")}
+
+
+def _paged_body(b: ModelBuilder, cfg: ModelConfig, page_size: int,
+                chunk: bool = False):
+    """Layer-scan body of the paged programs.  The (L, P, Hkv, ps, D)
+    K and V pools are carries, not per-layer xs: each layer scatters its
+    new rows into them in place at its own index (``w["layer"]``) and
+    gathers its view from the updated carry, so no layer copies or
+    re-stacks a pool slice.  consts: (cos, sin, pos, page_tbl)."""
+    def body(carries, w, consts):
+        h, pool_k, pool_v = carries
+        hh, (pool_k, pool_v) = _dense_block(
+            b, cfg, h, w, (consts[0], consts[1]), window=cfg.window,
+            cache=(pool_k, pool_v), pos=consts[2],
+            paged=(consts[3], page_size, w["layer"]), chunk=chunk)
+        return [hh, pool_k, pool_v], []
+    return body
 
 
 def build_dense(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGraphs:
@@ -238,22 +262,14 @@ def build_dense(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGraphs
         h = _embed(b, cfg, token)
         cosr, sinr = C.rope_tables_rows(b, pos, dh, cfg.rope_base)
 
-        def body(carries, w, consts):
-            hh, ex = _dense_block(
-                b, cfg, carries[0], w, (consts[0], consts[1]),
-                window=cfg.window, cache=(w["cache_k"], w["cache_v"]),
-                pos=consts[2], paged=(consts[3], ps))
-            return [hh], list(ex)
-
-        (h,), ys = b.scan_blocks(
-            "layers", cfg.n_layers, specs, body, [h],
-            consts=[cosr, sinr, pos, ptbl],
-            xs_extra={"cache_k": ck, "cache_v": cv},
-            n_ys=2, weight_inits=inits)
+        (h, ck, cv), _ = b.scan_blocks(
+            "layers", cfg.n_layers, specs, _paged_body(b, cfg, ps),
+            [h, ck, cv], consts=[cosr, sinr, pos, ptbl],
+            xs_extra=_layer_index(cfg.n_layers), weight_inits=inits)
         logits = _final_logits(b, cfg, h, last_only=True)
         sample = C.sample_tokens(logits, temp, tk, key, pos)
         return ModelGraphs(cfg, kind, b.finish(
-            [sample, ys[0], ys[1]], f"{cfg.name}_serve_paged"), b,
+            [sample, ck, cv], f"{cfg.name}_serve_paged"), b,
             {"cache_names": ["cache_k", "cache_v"],
              "state_out_names": ["cache_k", "cache_v"],
              "sample_output": 0, "page_size": ps, "max_pages": mp,
@@ -367,19 +383,12 @@ def _build_paged_chunk(cfg: ModelConfig, max_len: int, batch: int,
                                cp_tok.out(), axis=0), C.BATCH_SPEC)
     cosr, sinr = C.rope_tables_rows(b, cp_pos.out(), dh, cfg.rope_base)
 
-    def body(carries, w, consts):
-        hh, ex = _dense_block(
-            b, cfg, carries[0], w, (consts[0], consts[1]),
-            window=cfg.window, cache=(w["cache_k"], w["cache_v"]),
-            pos=consts[2], paged=(consts[3], ps))
-        return [hh], list(ex)
-
     xs_extra = dict(zip(wnames, c_stacked))
-    xs_extra["cache_k"] = cp_ck.out()
-    xs_extra["cache_v"] = cp_cv.out()
-    (h,), ys = b.scan_blocks(
-        "chunk_layers", L, {}, body, [h],
-        consts=[cosr, sinr, cp_pos.out(), c_ptbl], xs_extra=xs_extra, n_ys=2)
+    xs_extra.update(_layer_index(L))
+    (h, new_ck, new_cv), _ = b.scan_blocks(
+        "chunk_layers", L, {}, _paged_body(b, cfg, ps),
+        [h, cp_ck.out(), cp_cv.out()],
+        consts=[cosr, sinr, cp_pos.out(), c_ptbl], xs_extra=xs_extra)
     if cfg.norm == "layernorm":
         h = ops.layer_norm(h, c_gf, c_bf, eps=cfg.norm_eps)
     else:
@@ -392,7 +401,7 @@ def _build_paged_chunk(cfg: ModelConfig, max_len: int, batch: int,
     sample = C.sample_tokens(logits, c_temp, c_tk, c_key, cp_pos.out())
     new_pos = cp_pos.out() + ops.constant(1, dtype="i32")
     body_fn = Function([cp_tok, cp_pos, cp_ck, cp_cv] + const_params,
-                       [sample, new_pos, ys[0], ys[1], sample],
+                       [sample, new_pos, new_ck, new_cv, sample],
                        name=f"{cfg.name}_paged_chunk_body")
 
     outs = ops.scan(body_fn, [token, pos, ck, cv], xs=[],
@@ -433,7 +442,10 @@ def build_dense_chunk(cfg: ModelConfig, max_len: int, batch: int,
     shared page pool of ``n_pages`` pages (default: one trash page plus
     ``batch * ceil(max_len/page_size)``) routed via a per-row page table,
     and in-graph stochastic sampling (temperature / top-k / PRNG key as
-    inputs, temperature 0 = greedy):
+    inputs, temperature 0 = greedy).  The whole (L, P, ...) pools are
+    carries of the layer scan: each layer scatters its rows' new K/V in
+    place (:func:`~.components.paged_write`) and gathers its view from
+    the updated carry, so a step moves the rows it writes, not the pool:
 
     (token (B,1), pos (B,), page_tbl (B,MP), temperature (B,),
      top_k (B,), key (B,), cache_k (L,P,Hkv,ps,Dh), cache_v, *W) ->
@@ -523,10 +535,11 @@ def build_dense_paged_prefill(cfg: ModelConfig, max_len: int, chunk: int, *,
 
     A (1, C) slice of a single request's prompt at base position ``pos``
     writes its K/V rows straight into the shared page pool (the
-    :func:`~.components.paged_write` blend over the chunk — no dense
-    (1, P) cache, no host-side scatter) and returns the last row's
-    logits, so the final chunk of a prompt yields the request's first
-    token.  The engine admits these chunks through the same scheduler
+    :func:`~.components.paged_write` in-place scatter of the chunk's C
+    rows into the pools the layer scan carries — no dense (1, P) cache,
+    no host-side scatter, no other page touched) and returns the last
+    row's logits, so the final chunk of a prompt yields the request's
+    first token.  The engine admits these chunks through the same scheduler
     step as decode rows: a long prompt no longer stalls in-flight
     decodes for a whole dense prefill.
 
@@ -562,20 +575,12 @@ def build_dense_paged_prefill(cfg: ModelConfig, max_len: int, chunk: int, *,
     # chunked prefill token-identical to dense prefill
     cos, sin = C.rope_tables_sliced(b, max_len, dh, Cn, cfg.rope_base, pos)
 
-    def body(carries, w, consts):
-        hh, ex = _dense_block(
-            b, cfg, carries[0], w, (consts[0], consts[1]),
-            window=cfg.window, cache=(w["cache_k"], w["cache_v"]),
-            pos=consts[2], paged=(consts[3], ps), chunk=True)
-        return [hh], list(ex)
-
-    (h,), ys = b.scan_blocks(
-        "layers", cfg.n_layers, specs, body, [h],
-        consts=[cos, sin, pos, ptbl],
-        xs_extra={"cache_k": ck, "cache_v": cv},
-        n_ys=2, weight_inits=inits)
+    (h, ck, cv), _ = b.scan_blocks(
+        "layers", cfg.n_layers, specs, _paged_body(b, cfg, ps, chunk=True),
+        [h, ck, cv], consts=[cos, sin, pos, ptbl],
+        xs_extra=_layer_index(cfg.n_layers), weight_inits=inits)
     logits = _final_logits(b, cfg, h, last_only=True)
-    fn = b.finish([logits, ys[0], ys[1]], f"{cfg.name}_paged_prefill{Cn}")
+    fn = b.finish([logits, ck, cv], f"{cfg.name}_paged_prefill{Cn}")
     return ModelGraphs(cfg, "prefill_paged", fn, b,
                        {"cache_names": ["cache_k", "cache_v"],
                         "state_out_names": ["cache_k", "cache_v"],

@@ -259,6 +259,23 @@ def _(node, args):
     return [out]
 
 
+@_ev("ScatterSet")
+def _(node, args):
+    op, idx, upd = args
+    axes = node.attrs["axes"]
+    out = op.copy()
+    idx = idx.reshape(-1, len(axes))
+    win = [d for d in range(op.ndim) if d not in axes]
+    rows = upd.reshape((idx.shape[0],) + tuple(op.shape[d] for d in win))
+    dims = np.array([op.shape[a] for a in axes])
+    for n in np.flatnonzero(np.all((idx >= 0) & (idx < dims), axis=1)):
+        index = [slice(None)] * op.ndim
+        for k, a in enumerate(axes):
+            index[a] = int(idx[n, k])
+        out[tuple(index)] = rows[n]
+    return [out]
+
+
 def _clamp_starts(starts, shape, sizes):
     return [
         int(np.clip(int(s), 0, dim - sz))

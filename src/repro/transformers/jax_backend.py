@@ -267,6 +267,30 @@ def _(node, args, ctx):
     return [op.at[idx].add(upd.astype(op.dtype))]
 
 
+@_em("ScatterSet")
+def _(node, args, ctx):
+    # Every dim up to the last indexed axis becomes an index: those the op
+    # does not index, by an iota over their (local) extent.  The window
+    # XLA scatters is then only the trailing dims.  On the (L, P, Hkv, ps,
+    # D) page pool (axes 0, 1, 3) a (Hkv, D) window with ps inserted
+    # between makes the TPU's layout assignment carry the pool in another
+    # layout than the program's parameters, and copy the whole pool into
+    # and out of every call; rows of D keep the parameters' layout.
+    op, idx, upd = args
+    axes = node.attrs["axes"]
+    nb = idx.ndim - 1
+    lead = [d for d in range(max(axes) + 1) if d not in axes]
+    index = [slice(None)] * op.ndim
+    for k, a in enumerate(axes):
+        index[a] = idx[..., k].reshape(idx.shape[:-1] + (1,) * len(lead))
+    for j, d in enumerate(lead):
+        shape = [1] * (nb + len(lead))
+        shape[nb + j] = op.shape[d]
+        index[d] = jnp.arange(op.shape[d], dtype=idx.dtype).reshape(shape)
+    return [op.at[tuple(index)].set(upd, mode="drop",
+                                    wrap_negative_indices=False)]
+
+
 @_em("DynamicSlice")
 def _(node, args, ctx):
     return [lax.dynamic_slice(args[0], args[1:], node.attrs["sizes"])]

@@ -16,11 +16,14 @@ lasts ``--seconds``.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` traces
 the middle of the window and reports its per-layer metrics, each read by
-``metrics/<metric>.py``.  After the window, the program's state is freed
-and a sample of the finished requests is compared with the plain
-reference (``reference.py``), over weights it makes anew from the seed;
-each number compared is printed beside its limit, on standard error and
-under ``checks`` in the result.
+``metrics/<metric>.py`` from the reduced device trace, the engine's
+gauges and counters, and the program's host spans.  The device trace is
+reduced on a TPU only; a traced run elsewhere (a test's, on the CPU)
+reports what the spans and counters give.  After the window, the
+program's state is freed and a sample of the finished requests is
+compared with the plain reference (``reference.py``), over weights it
+makes anew from the seed; each number compared is printed beside its
+limit, on standard error and under ``checks`` in the result.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -89,6 +92,7 @@ def main(argv=None, control: bool = False) -> int:
     bench = cells.benchmark()
     wl = cells.workload(args.workload, bench)
     conf = cells.load_config(wl["config"])
+    cells.counts(conf)      # a configuration names its count module
     mix = cells.load_traffic(wl["traffic"])
     e2e = cells.metrics_for(wl["name"], "end_to_end", bench)
     layer = cells.metrics_for(wl["name"], "per_layer", bench)
@@ -171,6 +175,9 @@ def _run(args, wl, conf, mix, e2e, layer, readers, client, devs,
     drained = bool(srv.drain_ok) and engine.pool.pages_in_use == 0
     stats = dev.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
+    # the reduction reads the TPU's device planes, and the peaks are the
+    # TPU's
+    on_tpu = dev.platform == "tpu"
     run = Run(cell=wl["name"], conf=conf, mix=mix, engine=knobs, t0=t0,
               t_end=t_end,
               requests=traffic.make_requests(mix, args.seed, conf["vocab"],
@@ -180,7 +187,10 @@ def _run(args, wl, conf, mix, e2e, layer, readers, client, devs,
                                          "end": seen["end"]},
               samples=seen["samples"], trace_t0=seen.get("trace_t0"),
               trace_t1=seen.get("trace_t1"),
-              peaks=cells.peaks(dev.device_kind) if args.trace else None)
+              peaks=cells.peaks(dev.device_kind) if args.trace and on_tpu
+              else None,
+              spans=engine.tracer.spans_between(seen["trace_t0"], t_end)
+              if args.trace else None)
     lat = run.lateness_ms()
     log(f"load generator: {len(run.attempted())} requests sent, lateness "
         f"p50 {percentile(lat, 50)} ms, p99 {percentile(lat, 99)} ms, max "
@@ -199,12 +209,15 @@ def _run(args, wl, conf, mix, e2e, layer, readers, client, devs,
     if args.trace:
         from servebench import tracereduce
 
-        run.trace = tracereduce.reduce(tracereduce.load_xplane(trace_dir))
+        if on_tpu:
+            run.trace = tracereduce.reduce(
+                tracereduce.load_xplane(trace_dir))
+            device["busy_s"] = run.trace.busy_s
+            device["window_s"] = run.trace.window_s
+            breakdown = {
+                "device_ops": [list(x) for x in run.trace.device_ops],
+                "idle_gaps": [list(x) for x in run.trace.idle_gaps]}
         shutil.rmtree(os.path.dirname(trace_dir), ignore_errors=True)
-        device["busy_s"] = run.trace.busy_s
-        device["window_s"] = run.trace.window_s
-        breakdown = {"device_ops": [list(x) for x in run.trace.device_ops],
-                     "idle_gaps": [list(x) for x in run.trace.idle_gaps]}
         for m in layer:
             v = readers[m["name"]].read(run)
             if v is not None:

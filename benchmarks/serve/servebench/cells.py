@@ -7,17 +7,25 @@ per-layer metric sits in a file of its own, found by the name that
 * ``configs/<config>.json``: the model as it is run (the fields of the
   program's ``ModelConfig``), the published source, ``reduced``,
   ``assumed``, the departures from the published architecture, the
-  deployment it stands for, and the engine fields a deployment fixes
-  (``engine``: ``slots``, ``max_len``, ``pages``);
+  deployment it stands for, the engine fields a deployment fixes
+  (``engine``: ``slots``, ``max_len``, ``pages``), and the names of two
+  modules of its own: ``reference``, its plain reference beside
+  ``bench.py`` (``make_weights``, ``Reference``), and ``counts``, the
+  module in ``servebench/`` that counts the operations and bytes its
+  work needs (:data:`COUNT_FUNCTIONS`);
 * ``traffic/<mix>.json``: the parameters the one generator reads
   (:mod:`servebench.traffic`);
-* ``metrics/<metric>.py``: a reader with ``read(run) -> float | None``.
+* ``metrics/<metric>.py``: a reader with ``read(run) -> float | None``,
+  ``None`` where the run holds nothing for it to read.
 
-Adding a cell, a configuration, a mix or a metric is adding files.
+Adding a cell, a configuration (with its reference and count module), a
+mix or a metric is adding these files and their entries in
+``BENCHMARK.json``; no file that is there changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import os
@@ -130,6 +138,37 @@ def load_metric_readers(names: List[str]) -> Dict[str, object]:
         spec.loader.exec_module(mod)
         out[name] = mod
     return out
+
+
+# what a count module provides; each takes the configuration first
+COUNT_FUNCTIONS = (
+    "prefill_flops",        # (conf, start, end): prompt positions start..end-1
+    "decode_token_flops",   # (conf, pos): one decoded row, input at pos
+    "decode_token_bytes",   # (conf, pos): its bytes beyond the weights
+    "weight_bytes",         # (conf): the weights one decode step reads
+)
+
+
+def counts(conf: dict):
+    """The configuration's count module, ``servebench/<conf["counts"]>.py``.
+    A configuration that names none, or names a module that is missing or
+    lacks one of :data:`COUNT_FUNCTIONS`, is an error: no configuration
+    is counted as dense by default."""
+    name = conf.get("counts")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise CellError(f"{conf.get('name')}: names no count module "
+                        f"('counts': {name!r})")
+    path = os.path.join(SERVE_DIR, "servebench", f"{name}.py")
+    if not os.path.exists(path):
+        raise CellError(f"{conf.get('name')}: count module {name!r} has no "
+                        f"file {path}")
+    mod = importlib.import_module(f"{__package__}.{name}")
+    missing = [f for f in COUNT_FUNCTIONS
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise CellError(f"{conf.get('name')}: count module {name!r} lacks "
+                        f"{missing}")
+    return mod
 
 
 def peaks(device_kind: str) -> dict:

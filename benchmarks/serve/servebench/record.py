@@ -3,8 +3,9 @@
 A :class:`Run` is what the per-layer readers (``metrics/<name>.py``) get:
 the cell's configuration and mix, the engine's knobs, the window, the
 requests, the client's per-request records, the engine's gauges sampled
-through the window, and the reduced trace of a traced run.  All times
-are ``time.perf_counter()`` instants; the client shares that clock.
+through the window, and, of a traced run, the reduced device trace and
+the program's host spans.  All times are ``time.perf_counter()``
+instants; the client and the program's spans share that clock.
 """
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import flops
+from . import cells
 
 
 def percentile(values, q: float) -> Optional[float]:
@@ -38,6 +39,10 @@ class Run:
     trace_t0: Optional[float] = None    # traced window, perf_counter
     trace_t1: Optional[float] = None
     peaks: Optional[dict] = None
+    # the program tracer's spans from the traced window's start to the
+    # window's close (``Tracer.spans_between``); None untraced, or where
+    # the tracer's ring dropped one of them
+    spans: Optional[list] = None
 
     @property
     def seconds(self) -> float:
@@ -137,13 +142,14 @@ class Run:
     def needed_flops(self) -> float:
         """Operations the window's work needs: prefills whose first token
         arrived in the window (their uncached positions) and decoded
-        tokens that arrived in it."""
-        conf = self.conf
+        tokens that arrived in it, as the configuration's count module
+        counts them."""
+        conf, counts = self.conf, cells.counts(self.conf)
         total = 0.0
         for r in self.records:
             if r["times"] and self.inside(r["times"][0]):
                 req = self.requests[r["idx"]]
-                total += flops.prefill_flops(conf, 0, len(req.prompt))
+                total += counts.prefill_flops(conf, 0, len(req.prompt))
         for req, i in self.decode_tokens(self.t0, self.t_end):
-            total += flops.decode_token_flops(conf, len(req.prompt) + i - 1)
+            total += counts.decode_token_flops(conf, len(req.prompt) + i - 1)
         return total

@@ -55,6 +55,11 @@ def _metric(name, unit, cells):
     return m
 
 
+def _read(*path) -> dict:
+    with open(os.path.join(*path) + ".json") as fh:
+        return json.load(fh)
+
+
 def make(root: str, with_program: bool = True) -> str:
     """Build the checkout under ``root``; returns its bench.py."""
     dst = os.path.join(root, "benchmarks", "serve")
@@ -63,10 +68,8 @@ def make(root: str, with_program: bool = True) -> str:
     if with_program:
         os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
     cdir = os.path.join(dst, "configs")
-    with open(os.path.join(cdir, "deepseek-7b-l4.json")) as fh:
-        dense = _tiny(json.load(fh), "tiny-dense", 4)
-    with open(os.path.join(cdir, "granite-34b-code-l4.json")) as fh:
-        mqa = _tiny(json.load(fh), "tiny-mqa", 1)
+    dense = _tiny(_read(cdir, "deepseek-7b-l4"), "tiny-dense", 4)
+    mqa = _tiny(_read(cdir, "granite-34b-code-l4"), "tiny-mqa", 1)
     for conf in (dense, mqa):
         with open(os.path.join(cdir, conf["name"] + ".json"), "w") as fh:
             json.dump(conf, fh)
@@ -74,20 +77,29 @@ def make(root: str, with_program: bool = True) -> str:
         with open(os.path.join(dst, "traffic", mix["name"] + ".json"),
                   "w") as fh:
             json.dump(mix, fh)
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        bench = json.load(fh)
+    bench = _read(REPO, "BENCHMARK")
+    # the real benchmark's end-to-end metrics, each on the tiny cells of
+    # the loop its cells run (on every tiny cell where it lists none),
+    # and the time to first token, which the harness reports and the
+    # open-loop cell rehearses
+    loop = {w["name"]: _read(SERVE, "traffic", w["traffic"])["loop"]
+            for w in bench["workloads"]}
     bench["workloads"] = [
         {"name": "tiny-closed", "config": "tiny-dense",
          "traffic": "tiny-closed", "chips": 1, "why": "rehearsal"},
         {"name": "tiny-open", "config": "tiny-mqa",
          "traffic": "tiny-open", "chips": 1, "why": "rehearsal"}]
-    # every metric the harness can report, on the tiny cell whose loop
-    # reports it, whichever cells the real benchmark holds today
-    bench["end_to_end"] = [
-        _metric(n, u, w) for n, u, w in (
-            ("tok_s", "tokens/s", ["tiny-closed"]), ("itl_p95_ms", "ms", None),
-            ("ttft_p50_ms", "ms", ["tiny-open"]),
-            ("ttft_p95_ms", "ms", ["tiny-open"]), ("setup_s", "s", None))]
+    e2e = []
+    for m in bench["end_to_end"]:
+        m = dict(m)
+        if "workloads" in m:
+            loops = {loop[w] for w in m["workloads"]}
+            m["workloads"] = [c for c in MIXES if MIXES[c]["loop"] in loops]
+        e2e.append(m)
+    e2e += [_metric(n, "ms", ["tiny-open"]) for n in ("ttft_p50_ms",
+                                                       "ttft_p95_ms")
+            if n not in {m["name"] for m in e2e}]
+    bench["end_to_end"] = e2e
     readers = sorted(f[:-3] for f in os.listdir(os.path.join(dst, "metrics"))
                      if f.endswith(".py"))
     bench["per_layer"] = [

@@ -27,7 +27,8 @@ def checkout(tmp_path_factory):
 
 @pytest.mark.parametrize("cell,e2e", [
     ("tiny-closed", {"tok_s", "itl_p95_ms", "setup_s"}),
-    ("tiny-open", {"itl_p95_ms", "ttft_p50_ms", "ttft_p95_ms", "setup_s"})])
+    ("tiny-open", {"tok_s", "itl_p95_ms", "ttft_p50_ms", "ttft_p95_ms",
+                   "setup_s"})])
 def test_cpu_rehearsal(checkout, cell, e2e):
     root, bench = checkout
     rc, out, err = tiny.run(root, bench, "--workload", cell, "--seed", SEED,
@@ -42,6 +43,28 @@ def test_cpu_rehearsal(checkout, cell, e2e):
     assert res["checks"]["compiles_in_window"]["value"] == 0
     assert "check served_gap_max" in err
     assert "lateness" in out
+
+
+def test_cpu_traced_rehearsal(checkout):
+    """A traced run off the TPU reduces no device trace and reads no
+    peaks; the per-layer metrics that the engine's gauges and the
+    program's host spans give are there, the device's are not."""
+    root, bench = checkout
+    rc, out, err = tiny.run(root, bench, "--workload", "tiny-closed", "--seed",
+                            SEED + 1, "--seconds", 2, "--trace", 1,
+                            "--platform", "cpu")
+    res = tiny.result(out)
+    assert rc == 0 and res is not None, err[-3000:]
+    assert res["correct"] is True, err[-3000:]
+    got = res["metrics"]
+    assert {"decode_rows_mean.tok", "step_host_ms.tok", "dispatch_ms.tok",
+            "front_door_ms.tok"} <= set(got)
+    assert not {"decode_chunk_ms.tok", "decode_roofline.tok", "mfu.tok",
+                "idle_share.tok"} & set(got)
+    assert all(got[n]["value"] > 0 and got[n]["unit"] == "x"
+               for n in ("step_host_ms.tok", "dispatch_ms.tok",
+                         "front_door_ms.tok"))
+    assert "busy_s" not in res["device"] and "breakdown" not in res
 
 
 def test_control_run_is_not_correct(checkout):

@@ -84,8 +84,12 @@ def test_stream_outlasts_the_window():
     assert traffic.stream_length(CLOSED, 4, 10) >= 1.0 * 8 * 10
 
 
-DENSE = {"n_layers": 2, "d_model": 8, "n_heads": 2, "n_kv_heads": 1,
-         "d_ff": 16, "vocab": 11, "mlp": "swiglu"}
+DENSE = {"name": "dense", "n_layers": 2, "d_model": 8, "n_heads": 2,
+         "n_kv_heads": 1, "d_ff": 16, "vocab": 11, "mlp": "swiglu",
+         "counts": "flops"}
+# every reader in metrics/, found when the tests are collected
+READERS = sorted(f[:-3] for f in os.listdir(os.path.join(SERVE, "metrics"))
+                 if f.endswith(".py"))
 
 
 def test_flop_and_byte_counts():
@@ -102,9 +106,9 @@ def test_flop_and_byte_counts():
     assert flops.decode_token_bytes(DENSE, 5) == 7 * 32
 
 
-def _run(records, loop="closed"):
+def _run(records, loop="closed", conf=DENSE):
     reqs = traffic.make_requests(CLOSED, 1, 500, 4)
-    return Run(cell="c", conf=dict(DENSE), mix={"loop": loop},
+    return Run(cell="c", conf=dict(conf), mix={"loop": loop},
                engine={"page_size": 8, "chunk_steps": 4}, t0=10.0,
                t_end=20.0, requests=reqs, records=records)
 
@@ -177,11 +181,44 @@ def test_benchmark_names_resolve_to_files():
         cells.peaks("cpu")
 
 
-def test_readers_return_nothing_without_a_trace():
-    names = sorted(f[:-3] for f in os.listdir(os.path.join(SERVE, "metrics"))
-                   if f.endswith(".py"))
-    readers = cells.load_metric_readers(names)
+@pytest.mark.parametrize("name", READERS)
+def test_readers_return_nothing_without_a_trace(name):
+    reader = cells.load_metric_readers([name])[name]
     run = _run([_rec(0, 10.0, [10.5, 11.0])])
-    assert len(names) == 8
-    for name in names:
-        assert readers[name].read(run) is None, name
+    assert reader.read(run) is None
+
+
+@pytest.mark.parametrize("bad,match", [
+    ({"counts": None}, "names no count module"),
+    ({"counts": "../flops"}, "names no count module"),
+    ({"counts": "no_such_counts"}, "has no file"),
+    ({"counts": "traffic"}, "lacks")],
+    ids=["unnamed", "a-path", "no-file", "not-a-count-module"])
+def test_count_module_is_required(bad, match):
+    conf = dict(DENSE, **bad)
+    if bad["counts"] is None:
+        del conf["counts"]
+    with pytest.raises(cells.CellError, match=match):
+        cells.counts(conf)
+    with pytest.raises(cells.CellError, match=match):
+        _run([_rec(0, 10.0, [10.5, 11.0])], conf=conf).needed_flops()
+
+
+def test_dense_counts_read_as_before():
+    """The dense count module, named by the configuration, gives the
+    operations the harness counted when it called ``flops`` directly:
+    the same sums in the same order, bit for bit."""
+    for name in ("deepseek-7b-l4", "granite-34b-code-l4"):
+        assert cells.counts(cells.load_config(name)) is flops
+    conf = dict(cells.load_config("deepseek-7b-l4"))
+    run = _run([_rec(0, 10.0, [10.5, 11.0, 11.5, 25.0]),
+                _rec(1, 9.0, [9.5, 10.5]), _rec(2, 11.0, [12.0, 12.5])],
+               conf=conf)
+    want = 0.0
+    for r in run.records:
+        if r["times"] and run.inside(r["times"][0]):
+            want += flops.prefill_flops(
+                conf, 0, len(run.requests[r["idx"]].prompt))
+    for req, i in run.decode_tokens(run.t0, run.t_end):
+        want += flops.decode_token_flops(conf, len(req.prompt) + i - 1)
+    assert run.needed_flops() == want

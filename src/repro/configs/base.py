@@ -40,6 +40,13 @@ class ModelConfig:
     first_dense: int = 0            # leading dense layers before MoE stack
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # the drop-free routed layer of the serving graphs (models/moe.py
+    # apply_routed: DeepSeek-V3's sigmoid gate with its correction bias);
+    # the train graph keeps apply_moe's softmax capacity router
+    n_group: int = 1                # expert groups, topk_group kept a token
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    experts_held: int = 0           # routed experts held here; 0 = all
     # -- MLA (deepseek-v3) ------------------------------------------------
     mla: bool = False
     q_lora: int = 0
@@ -49,6 +56,14 @@ class ModelConfig:
     d_v: int = 0
     mtp: bool = False
     mtp_weight: float = 0.3
+    # YaRN rotary scaling (yarn_factor 0 = plain RoPE); yarn_mscale is the
+    # published mscale and mscale_all_dim, equal in every DeepSeek config,
+    # so it scales the softmax and leaves the rotary tables unscaled
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
     # -- recurrent hybrid (recurrentgemma) ---------------------------------
     pattern: Tuple[str, ...] = ()   # e.g. ("rec", "rec", "attn")
     lru_width: int = 0
@@ -79,6 +94,11 @@ class ModelConfig:
         return self.d_head or self.d_model // self.n_heads
 
     @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
     def sub_quadratic(self) -> bool:
         """Can decode with O(1)-per-token state (ring/recurrent caches)?"""
         return (self.family in ("rg_hybrid", "xlstm")
@@ -104,6 +124,7 @@ class ModelConfig:
             n_experts=4 if self.n_experts else 0,
             top_k=min(self.top_k, 2),
             n_shared_experts=min(self.n_shared_experts, 1),
+            experts_held=0,
             lru_width=64 if self.lru_width else 0,
             local_window=8 if self.pattern else 2048,
             window=8 if self.window is not None else None,
@@ -116,6 +137,10 @@ class ModelConfig:
             r = dataclasses.replace(r, n_layers=len(self.pattern) + 2)
         elif self.family == "mla_moe":
             r = dataclasses.replace(r, n_layers=3, first_dense=1)
+            if self.n_group > 1:
+                # enough experts that groups bite
+                r = dataclasses.replace(r, n_experts=16, top_k=4, n_group=4,
+                                        topk_group=2)
         elif self.family == "vlm":
             r = dataclasses.replace(r, n_layers=2 * self.cross_every)
         elif self.family == "encdec":

@@ -30,7 +30,11 @@ boundary.  Three modes, worst to best:
                      boundaries.  Sampling (temperature / top-k / PRNG
                      key) is in-graph per row; greedy (temperature 0, the
                      default) is token-for-token identical to
-                     ``continuous``.
+                     ``continuous``.  It serves every family in
+                     ``models.lm.PAGED_FAMILIES``: for ``mla_moe`` the
+                     pool holds MLA's two latent tensors, and the
+                     programs' routed-expert pairs are counted
+                     (``EXPERT_COUNTERS``).
 
 Donation invariants (see ROADMAP "Serving engine (PR 2)"):
   * the engine is the only owner of the pool buffers; after each raw
@@ -53,7 +57,7 @@ import numpy as np
 
 from ..backend import Backend, CompileOptions
 from ..configs.base import ModelConfig, ShapeConfig
-from ..models.lm import ModelGraphs, build_graphs
+from ..models.lm import PAGED_FAMILIES, ModelGraphs, build_graphs
 from .faults import FaultInjector, get_injector
 from .tracing import Tracer, get_tracer
 
@@ -72,6 +76,13 @@ HEALTH_STATES = ("ok", "degraded", "halted")
 _STEP_INPUTS = ("token", "pos")
 _PAGED_STEP_INPUTS = _STEP_INPUTS + ("page_tbl", "temperature", "top_k",
                                      "key")
+# lifecycle counters of an engine whose programs count routed pairs
+EXPERT_COUNTERS = ("expert_pairs", "expert_layer_steps",
+                   "expert_pairs_prefill", "expert_layer_steps_prefill")
+# a paged step's whole prefill chunks go out this many to a dispatch, so
+# one read of the weights serves several chunks (each prompt's ragged
+# last chunk runs alone)
+PREFILL_ROWS = 4
 # per-token latency samples kept for EngineReport's p50/p95: the newest
 # this many (a serving process runs for as long as it is up)
 LAT_SAMPLES = 1 << 14
@@ -987,10 +998,16 @@ class ServeEngine:
                 f"config must be an EngineConfig, got "
                 f"{type(config).__name__}")
         mode = config.mode
-        if mode != "lockstep" and cfg.family != "dense":
+        if mode == "paged" and cfg.family not in PAGED_FAMILIES:
             raise NotImplementedError(
-                f"mode {mode!r} needs the dense-family serve/chunk graphs; "
+                f"mode 'paged' serves the {PAGED_FAMILIES} families; "
                 f"{cfg.name} ({cfg.family}) serves via mode='lockstep'")
+        if mode in ("continuous", "donated") and cfg.family != "dense":
+            raise NotImplementedError(
+                f"mode {mode!r} serves the dense family only; {cfg.name} "
+                f"({cfg.family}) serves via mode='lockstep'"
+                + (" or mode='paged'" if cfg.family in PAGED_FAMILIES
+                   else ""))
         self.cfg = cfg
         self.config = config
         self.slots = int(config.slots)
@@ -1005,32 +1022,6 @@ class ServeEngine:
             config.backend, **({"device": config.device}
                                if config.device is not None else {}))
         self.base_options = config.compile_options(options)
-        if self.tp > 1:
-            for dim, val in (("n_heads", cfg.n_heads),
-                             ("n_kv_heads", cfg.n_kv_heads),
-                             ("d_ff", cfg.d_ff)):
-                if val % self.tp:
-                    raise ValueError(
-                        f"tp={self.tp} must divide {dim}={val} "
-                        f"({cfg.name})")
-            import jax
-            n_dev = len(jax.devices())
-            if n_dev < self.tp:
-                raise RuntimeError(
-                    f"tp={self.tp} needs >= {self.tp} devices but jax "
-                    f"sees {n_dev}; on CPU set XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count={self.tp}")
-            # the chunk + paged-prefill graphs compile partitioned: the
-            # PartitionGraph pass cuts them per-device and the backend
-            # shard_maps the result over a (tp,)-"model" mesh.  The
-            # dense prefill fallback (prefill_chunk=0) stays on
-            # base_options — it computes global caches that the host
-            # scatters into the (globally addressed) pool pages.
-            self._graph_options = self.base_options.replace(
-                mode="shardmap", partition="tp", mesh_shape=(self.tp,))
-        else:
-            self._graph_options = self.base_options
-
         if mode == "paged":
             # paged mode always dispatches the fused chunk graph — one
             # dispatch decodes chunk_steps tokens per row; chunk_steps=1
@@ -1053,6 +1044,7 @@ class ServeEngine:
             self.prefill_chunk = (4 * self.page_size
                                   if config.prefill_chunk is None
                                   else int(config.prefill_chunk))
+            self.prefill_rows = PREFILL_ROWS
             mp = -(-self.max_len // self.page_size)
             # default pool: the worst case (every slot at max_len) plus
             # the trash page — `pages` shrinks it to create admission
@@ -1079,6 +1071,45 @@ class ServeEngine:
                              else _STEP_INPUTS)
         self.cache_names = [n.name for n in b.inputs
                             if n.name not in self._step_inputs]
+        if mode == "paged" and self.prefill_chunk == 0:
+            dense = build_graphs(cfg, ShapeConfig(
+                "prefill", "prefill", self.page_size, 1), 1)
+            if dense.aux.get("cache_names") != self.cache_names:
+                raise ValueError(
+                    f"prefill_chunk=0 scatters the dense (1, P) prefill's "
+                    f"caches {dense.aux.get('cache_names')} into the "
+                    f"pool's pages, and {cfg.name}'s pool holds "
+                    f"{self.cache_names}: it prefills only through the "
+                    f"paged prefill chunk")
+        if self.tp > 1:
+            # the pools are (L, P, heads, page_size, D): tp cuts the heads
+            pool_heads = {n.out_types[0].shape[2] for n in b.inputs
+                          if n.name in self.cache_names}
+            for dim, val in (("n_heads", cfg.n_heads),
+                             ("d_ff", cfg.d_ff),
+                             ("the pool's heads per page",
+                              min(pool_heads))):
+                if val % self.tp:
+                    raise ValueError(
+                        f"tp={self.tp} must divide {dim}={val} "
+                        f"({cfg.name})")
+            import jax
+            n_dev = len(jax.devices())
+            if n_dev < self.tp:
+                raise RuntimeError(
+                    f"tp={self.tp} needs >= {self.tp} devices but jax "
+                    f"sees {n_dev}; on CPU set XLA_FLAGS="
+                    f"--xla_force_host_platform_device_count={self.tp}")
+            # the chunk + paged-prefill graphs compile partitioned: the
+            # PartitionGraph pass cuts them per-device and the backend
+            # shard_maps the result over a (tp,)-"model" mesh.  The
+            # dense prefill fallback (prefill_chunk=0) stays on
+            # base_options — it computes global caches that the host
+            # scatters into the (globally addressed) pool pages.
+            self._graph_options = self.base_options.replace(
+                mode="shardmap", partition="tp", mesh_shape=(self.tp,))
+        else:
+            self._graph_options = self.base_options
         # decode outputs 1..N map to the cache inputs they update, by
         # name (aux["state_out_names"]); inputs absent from the list are
         # step constants (e.g. whisper cross_k/v, vlm vision caches) and
@@ -1150,6 +1181,14 @@ class ServeEngine:
         self.health = "ok"
         self.counters: Dict[str, int] = dict.fromkeys(
             TERMINAL_STATUSES + ("engine_errors",), 0)
+        # routed-expert counters, where the paged programs return each
+        # token's (token, expert) pairs that landed on held experts:
+        # pairs, and MoE layer-steps of the rows that made them, of
+        # decode rows and of prefill tokens apart
+        self._pairs_out = self.graphs.aux.get("pairs_output")
+        self._moe_layers = int(self.graphs.aux.get("moe_layers", 0))
+        if self._pairs_out is not None:
+            self.counters.update(dict.fromkeys(EXPERT_COUNTERS, 0))
         self._events: List[Tuple[int, str, Optional[str]]] = []
         self._steps = 0
         # decode dispatches: their summed duration (self._steps counts
@@ -1168,10 +1207,11 @@ class ServeEngine:
         self._chunks: Dict[int, Tuple] = {}   # steps -> (graphs, compiled)
         # prompt-length -> (ModelGraphs, CompiledFunction, ordered jax params)
         self._prefill: Dict[Tuple[int, int], Tuple] = {}
-        # chunk-length -> (ModelGraphs, CompiledFunction, ordered jax
-        # params) for the in-graph paged prefill (PR 9); one entry per
-        # distinct chunk length (full chunks + ragged prompt tails)
-        self._pf_chunks: Dict[int, Tuple] = {}
+        # (chunk length, rows) -> (ModelGraphs, CompiledFunction, ordered
+        # jax params, recycle) for the in-graph paged prefill: the whole
+        # chunk's batched program (rows = prefill_rows) and one
+        # single-chunk program (rows None) per ragged prompt tail length
+        self._pf_chunks: Dict[Tuple[int, Optional[int]], Tuple] = {}
 
     # -- request intake ------------------------------------------------------
     def check_request(self, prompt_len: int, max_new: int, *,
@@ -1526,15 +1566,18 @@ class ServeEngine:
                 return True
         return False
 
-    def _paged_prefill_for(self, C: int):
-        """Compile (once per distinct chunk length) the paged prefill
-        graph: a (1, C) prompt slice written straight into the page pool,
-        cache buffers donated like the decode chunk."""
-        if C not in self._pf_chunks:
+    def _paged_prefill_for(self, C: int, rows: Optional[int] = None):
+        """Compile (once per distinct chunk length and row count) the
+        paged prefill graph: a (1, C) prompt slice, or ``rows`` chunks of
+        C (:func:`~repro.models.lm.build_dense_paged_prefill`), written
+        straight into the page pool, cache buffers donated like the
+        decode chunk."""
+        key = (C, rows)
+        if key not in self._pf_chunks:
             from ..models.lm import build_dense_paged_prefill
             g = build_dense_paged_prefill(
                 self.cfg, self.max_len, C, page_size=self.page_size,
-                n_pages=self.n_pages)
+                n_pages=self.n_pages, rows=rows)
             step_in = ("token", "pos", "page_tbl")
             cache_ix = tuple(i for i, n in enumerate(g.builder.inputs)
                              if n.name not in step_in)
@@ -1549,8 +1592,8 @@ class ServeEngine:
             out_names = g.aux["state_out_names"]
             recycle = [out_names.index(n) if n in out_names else None
                        for n in self.pool.names]
-            self._pf_chunks[C] = (g, cf, pvals, recycle)
-        return self._pf_chunks[C]
+            self._pf_chunks[key] = (g, cf, pvals, recycle)
+        return self._pf_chunks[key]
 
     def _begin_prefill(self, req: Request, slot: int) -> None:
         """Admit ``req`` into ``slot`` for chunked prefill: attach any
@@ -1573,7 +1616,8 @@ class ServeEngine:
 
     def _prefill_chunk_step(self, slot: int, req: Request) -> Optional[int]:
         """Advance ``slot``'s prefill by one chunk (one dispatch of at
-        most ``prefill_chunk`` prompt tokens).  On the chunk that
+        most ``prefill_chunk`` prompt tokens; the step loop gives it a
+        prompt's ragged last chunk).  On the chunk that
         completes the prompt: host-sample the first token from the
         returned last-row logits, publish the prefix pages, and hand the
         row over to decode — returning the first token.  Returns None
@@ -1599,6 +1643,8 @@ class ServeEngine:
                               *self.pool.buffers, *pvals)
             with tr.span("prefill.sync", rid) as sync:
                 logits = np.asarray(outs[0])  # (1, 1, V) — syncs the chain
+                pairs = (np.asarray(outs[self._pairs_out])
+                         if self._pairs_out is not None else None)
                 self.pool.update([self.pool.buffers[k] if j is None
                                   else outs[1 + j]
                                   for k, j in enumerate(recycle)])
@@ -1606,11 +1652,23 @@ class ServeEngine:
             self._contain_step_failure(exc)
             return None
         self.prefill_seconds += (sync.span.end_ns - prep.span.start_ns) / 1e9
+        if pairs is not None:
+            self.counters["expert_pairs_prefill"] += int(pairs.sum())
+            self.counters["expert_layer_steps_prefill"] += \
+                (hi - lo) * self._moe_layers
         req.prefill_pos = hi
         self.pool.note_used(slot, hi)
         if hi < P:
             return None
-        with tr.span("prefill.sample", rid):
+        return self._first_token(slot, req, logits)
+
+    def _first_token(self, slot: int, req: Request,
+                     logits: np.ndarray) -> int:
+        """The prompt's last chunk has run: host-sample the first token
+        from its last-row logits (1, 1, V), publish the prefix pages, and
+        hand the row over to decode."""
+        P = len(req.prompt)
+        with self.tracer.span("prefill.sample", req.rid):
             first = _host_sample(logits, req.temperature, req.top_k,
                                  req.key, P - 1)
             if self.prefix_sharing:
@@ -1623,6 +1681,91 @@ class ServeEngine:
             self._pos[slot] = P
             self.pool.note_used(slot, P)
         return first
+
+    def _plan_prefill_rows(self, prefilling):
+        """Split this step's prefills: every slot whose next chunk is a
+        full ``prefill_chunk`` gives it as a row of a batched dispatch,
+        the others run their ragged last chunk alone.  Rows left over in
+        the last dispatch take further full chunks of the slots already
+        in it, round the slots in order, so no row is padding while a
+        prompt still has full chunks to run.  Returns (rows as (slot,
+        request, start), singles as (slot, request))."""
+        C, R = self.prefill_chunk, self.prefill_rows
+        rows, singles, nxt = [], [], {}
+        for slot, req in prefilling:
+            lo = req.prefill_pos
+            if len(req.prompt) - lo >= C:
+                rows.append((slot, req, lo))
+                nxt[slot] = lo + C
+            else:
+                singles.append((slot, req))
+        firsts = list(rows)
+        spare = -len(rows) % R
+        while spare:
+            before = spare
+            for slot, req, _ in firsts:
+                if spare and len(req.prompt) - nxt[slot] >= C:
+                    rows.append((slot, req, nxt[slot]))
+                    nxt[slot] += C
+                    spare -= 1
+            if spare == before:
+                break
+        return rows, singles
+
+    def _prefill_rows_step(self, rows, emitted: List[Tuple[int, int]]
+                           ) -> bool:
+        """One batched dispatch of up to ``prefill_rows`` full chunks
+        ``rows`` ((slot, request, start) each; unused rows point at the
+        trash page).  Rows that finish their prompt sample its first
+        token into ``emitted``.  Returns False after a contained
+        dispatch failure."""
+        tr = self.tracer
+        C, R = self.prefill_chunk, self.prefill_rows
+        rid = rows[0][1].rid
+        with tr.span("prefill.prep", rid) as prep:
+            g, cf, pvals, recycle = self._paged_prefill_for(C, R)
+            tok = np.zeros((R, C), dtype=rows[0][1].prompt.dtype)
+            pos = np.zeros((R,), dtype=np.int32)
+            for i, (slot, req, lo) in enumerate(rows):
+                self.pool.ensure_pages(slot, lo + C - 1)
+                self.pool.prepare_writes(slot, lo, lo + C - 1)
+                tok[i] = req.prompt[lo:lo + C]
+                pos[i] = lo
+            # the table is read after every row's pages are in place
+            tbl = self.pool.page_table
+            ptbl = np.zeros((R, tbl.shape[1]), dtype=tbl.dtype)
+            for i, (slot, _, _) in enumerate(rows):
+                ptbl[i] = tbl[slot]
+        try:
+            with tr.span("prefill.dispatch", rid):
+                self.faults.delay("dispatch.delay")
+                self.faults.check("prefill.raise")
+                outs = cf.raw(tok, pos, ptbl, *self.pool.buffers, *pvals)
+            with tr.span("prefill.sync", rid) as sync:
+                logits = np.asarray(outs[0])  # (R, 1, V) — syncs the chain
+                pairs = (np.asarray(outs[self._pairs_out])  # (R*C,)
+                         if self._pairs_out is not None else None)
+                self.pool.update([self.pool.buffers[k] if j is None
+                                  else outs[1 + j]
+                                  for k, j in enumerate(recycle)])
+        except Exception as exc:
+            self._contain_step_failure(exc)
+            return False
+        self.prefill_seconds += (sync.span.end_ns - prep.span.start_ns) / 1e9
+        for i, (slot, req, lo) in enumerate(rows):
+            if pairs is not None:
+                self.counters["expert_pairs_prefill"] += \
+                    int(pairs[i * C:(i + 1) * C].sum())
+                self.counters["expert_layer_steps_prefill"] += \
+                    C * self._moe_layers
+            req.prefill_pos = lo + C
+            self.pool.note_used(slot, lo + C)
+            if lo + C == len(req.prompt):
+                emitted.append((req.rid, self._first_token(
+                    slot, req, logits[i:i + 1])))
+                if req.done:  # max_new == 1: done at prefill completion
+                    self._finish(req)
+        return True
 
     def step(self) -> List[Tuple[int, int]]:
         """One engine step: admit what fits, then one batched decode
@@ -1708,14 +1851,21 @@ class ServeEngine:
             emitted: List[Tuple[int, int]] = []
             with tr.span("sched.admit"):
                 self._admit_paged(emitted)
-            # advance chunked prefills — one chunk per prefilling slot per
-            # step, so long prompts share the step loop with decode rows
-            # instead of stalling them behind one dense prefill dispatch
-            for slot, rid in enumerate(list(self._slot_req)):
-                if rid is None:
-                    continue
-                req = self._requests[rid]
-                if req.finished or req.prefill_pos is None:
+            # advance chunked prefills — a chunk per prefilling slot per
+            # step (more where a batched dispatch has rows to spare), so
+            # long prompts share the step loop with decode rows instead
+            # of stalling them behind one dense prefill dispatch
+            rows, singles = self._plan_prefill_rows(
+                [(slot, req) for slot, req in
+                 ((s, self._requests[rid])
+                  for s, rid in enumerate(self._slot_req) if rid is not None)
+                 if not req.finished and req.prefill_pos is not None])
+            R = self.prefill_rows
+            for i in range(0, len(rows), R):
+                if not self._prefill_rows_step(rows[i:i + R], emitted):
+                    break
+            for slot, req in singles:
+                if req.finished:
                     continue
                 tok = self._prefill_chunk_step(slot, req)
                 if tok is not None:
@@ -1741,6 +1891,8 @@ class ServeEngine:
                                        *self.pool.buffers, *self.jparams)
                 with tr.span("decode.sync") as sync:
                     toks = np.asarray(outs[0])  # (K, B, 1) — syncs the chain
+                    pairs = (np.asarray(outs[self._pairs_out])  # (K, B)
+                             if self._pairs_out is not None else None)
                     self.pool.update([self.pool.buffers[k] if j is None
                                       else outs[1 + j]
                                       for k, j in enumerate(self._recycle)])
@@ -1761,6 +1913,11 @@ class ServeEngine:
                     req.pos += take
                     self.pool.note_used(slot, req.pos)
                     chunk_tokens += take
+                    if pairs is not None:
+                        self.counters["expert_pairs"] += \
+                            int(pairs[:take, slot].sum())
+                        self.counters["expert_layer_steps"] += \
+                            take * self._moe_layers
                     if req.done:
                         self._finish(req)
                 self._decode_tokens += chunk_tokens

@@ -57,8 +57,29 @@ def _rope_freq(d_head: int, base: float) -> np.ndarray:
                      / d_head)).astype(np.float32)
 
 
-def _rope_host_tables(seq: int, d_head: int,
-                      base: float) -> Tuple[np.ndarray, np.ndarray]:
+def yarn_freq(d: int, base: float, factor: float, original_len: int,
+              beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's (d//2,) f32 rotary frequencies, by the published rule
+    (DeepSeek-V3's ``DeepseekV3YarnRotaryEmbedding``): pairs that turn
+    more than ``beta_fast`` times over the original context keep their
+    frequency, pairs that turn fewer than ``beta_slow`` times are slowed
+    by ``factor``, and a linear ramp over the pair index blends the two
+    in between."""
+    theta = base ** (-np.arange(d // 2, dtype=np.float64) * 2.0 / d)
+
+    def pair(turns):   # the pair index that turns `turns` times
+        return d * math.log(original_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (theta / factor * ramp + theta * (1 - ramp)).astype(np.float32)
+
+
+def _rope_host_tables(seq: int, d_head: int, base: float,
+                      freq: Optional[np.ndarray] = None
+                      ) -> Tuple[np.ndarray, np.ndarray]:
     """Host-evaluated (seq, d_head//2) f32 cos/sin tables.
 
     Static-position tables are computed with numpy rather than left for
@@ -66,22 +87,27 @@ def _rope_host_tables(seq: int, d_head: int,
     differently (1 ulp in f32), so two compiled programs that must agree
     bitwise on the same positions — the dense prefill graph and the
     chunked paged-prefill graph — would otherwise write K rows that
-    disagree in the last bf16 bit and eventually flip a greedy argmax."""
-    ang = (np.arange(seq, dtype=np.float32)[:, None]
-           * _rope_freq(d_head, base)[None, :])
+    disagree in the last bf16 bit and eventually flip a greedy argmax.
+    ``freq`` replaces the plain frequencies (:func:`yarn_freq`)."""
+    if freq is None:
+        freq = _rope_freq(d_head, base)
+    ang = np.arange(seq, dtype=np.float32)[:, None] * freq[None, :]
     return np.cos(ang).astype(np.float32), np.sin(ang).astype(np.float32)
 
 
 def rope_tables(b: ModelBuilder, seq: int, d_head: int, base: float = 10000.0,
-                offset: Optional[Value] = None) -> Tuple[Value, Value]:
+                offset: Optional[Value] = None,
+                freq: Optional[np.ndarray] = None) -> Tuple[Value, Value]:
     """cos/sin tables (seq, d_head//2) in f32.  ``offset`` (scalar i32)
     shifts positions for decode.  Static tables (no offset) are baked as
-    host-computed literals (see :func:`_rope_host_tables`)."""
+    host-computed literals (see :func:`_rope_host_tables`).  ``freq``
+    replaces the plain frequencies (:func:`yarn_freq`)."""
     half = d_head // 2
     if offset is None:
-        cos, sin = _rope_host_tables(seq, d_head, base)
+        cos, sin = _rope_host_tables(seq, d_head, base, freq)
         return ops.constant(cos), ops.constant(sin)
-    freq = ops.constant(_rope_freq(d_head, base))  # (half,)
+    freq = ops.constant(_rope_freq(d_head, base) if freq is None
+                        else freq)  # (half,)
     pos = ops.iota((seq,), 0, "i32") + ops.broadcast_to(offset, (seq,))
     posf = ops.convert(pos, "f32")
     ang = ops.reshape(posf, (seq, 1)) * ops.reshape(freq, (1, half))
@@ -89,13 +115,15 @@ def rope_tables(b: ModelBuilder, seq: int, d_head: int, base: float = 10000.0,
 
 
 def rope_tables_sliced(b: ModelBuilder, max_len: int, d_head: int, chunk: int,
-                       base: float, offset: Value) -> Tuple[Value, Value]:
+                       base: float, offset: Value,
+                       freq: Optional[np.ndarray] = None
+                       ) -> Tuple[Value, Value]:
     """``chunk`` rows of the full host-computed table starting at the
     traced row ``offset`` — bitwise identical to the corresponding rows
     of a static :func:`rope_tables` by construction, which is what makes
     chunked paged prefill token-exact against dense prefill."""
     half = d_head // 2
-    cos, sin = _rope_host_tables(max_len, d_head, base)
+    cos, sin = _rope_host_tables(max_len, d_head, base, freq)
     zero = ops.constant(np.int32(0))
     return (ops.dynamic_slice(ops.constant(cos), [offset, zero],
                               [chunk, half]),
@@ -103,16 +131,34 @@ def rope_tables_sliced(b: ModelBuilder, max_len: int, d_head: int, chunk: int,
                               [chunk, half]))
 
 
+def rope_tables_sliced_rows(b: ModelBuilder, max_len: int, d_head: int,
+                            chunk: int, base: float, pos: Value,
+                            freq: Optional[np.ndarray] = None
+                            ) -> Tuple[Value, Value]:
+    """:func:`rope_tables_sliced` for a batch of chunks: ``pos`` (R,) i32
+    base positions -> (R, chunk, d_head//2) f32 tables, row ``r`` holding
+    the host table's rows ``pos[r] .. pos[r]+chunk-1`` (a gather, so each
+    row is bitwise the single-chunk slice)."""
+    R = pos.shape[0]
+    cos, sin = _rope_host_tables(max_len, d_head, base, freq)
+    idx = ops.broadcast_in_dim(pos, (R, chunk), (0,)) \
+        + ops.iota((R, chunk), 1, "i32")
+    return (ops.gather(ops.constant(cos), idx, axis=0),
+            ops.gather(ops.constant(sin), idx, axis=0))
+
+
 def rope_tables_rows(b: ModelBuilder, pos: Value, d_head: int,
-                     base: float = 10000.0) -> Tuple[Value, Value]:
+                     base: float = 10000.0,
+                     freq: Optional[np.ndarray] = None
+                     ) -> Tuple[Value, Value]:
     """Per-row cos/sin tables from a *vector* of absolute positions:
     ``pos`` (B,) i32 -> (B, d_head//2) f32 tables.  The continuous-batching
-    serve graph uses this so each batch row can sit at its own position."""
+    serve graph uses this so each batch row can sit at its own position.
+    ``freq`` replaces the plain frequencies (:func:`yarn_freq`)."""
     half = d_head // 2
     B = pos.shape[0]
-    freq = ops.constant(
-        (base ** (-np.arange(half, dtype=np.float64) * 2.0 / d_head))
-        .astype(np.float32))  # (half,)
+    freq = ops.constant(_rope_freq(d_head, base) if freq is None
+                        else freq)  # (half,)
     posf = ops.convert(pos, "f32")
     ang = ops.reshape(posf, (B, 1)) * ops.reshape(freq, (1, half))
     return ops.cos(ang), ops.sin(ang)
@@ -133,13 +179,16 @@ def apply_rope_rows(x: Value, cos: Value, sin: Value) -> Value:
 
 
 def apply_rope(x: Value, cos: Value, sin: Value) -> Value:
-    """x: (B, H, S, D); cos/sin: (S, D//2).  Rotate-half convention."""
+    """x: (B, H, S, D); cos/sin: (S, D//2), or (B, S, D//2) for rows at
+    positions of their own (:func:`rope_tables_sliced_rows`).
+    Rotate-half convention."""
     B, H, S, D = x.shape
     half = D // 2
     x1 = ops.slice_(x, [0, 0, 0, 0], [B, H, S, half])
     x2 = ops.slice_(x, [0, 0, 0, half], [B, H, S, D])
-    c = ops.reshape(cos, (1, 1, S, half))
-    s = ops.reshape(sin, (1, 1, S, half))
+    lead = B if cos.rank == 3 else 1
+    c = ops.reshape(cos, (lead, 1, S, half))
+    s = ops.reshape(sin, (lead, 1, S, half))
     c = ops.convert(ops.broadcast_to(c, (B, H, S, half)), x.dtype)
     s = ops.convert(ops.broadcast_to(s, (B, H, S, half)), x.dtype)
     return ops.concat([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
@@ -446,7 +495,8 @@ def _chunkpos_attend(q: Value, cache_k: Value, cache_v: Value, kpos: Value,
         ops.broadcast_to(ops.constant(1.0 / math.sqrt(d_head), dtype="f32"),
                          (B, n_kv, rep, Cq, Skv)))
     kpos3 = ops.broadcast_to(ops.reshape(kpos, (B, 1, Skv)), (B, Cq, Skv))
-    qpos3 = ops.broadcast_to(ops.reshape(qpos, (1, Cq, 1)), (B, Cq, Skv))
+    qpos3 = ops.broadcast_to(
+        ops.reshape(qpos, (B if qpos.rank == 2 else 1, Cq, 1)), (B, Cq, Skv))
     mask = ops.less_equal(kpos3, qpos3)
     if window is not None:
         w = ops.constant(window, dtype="i32")
@@ -475,7 +525,12 @@ def paged_prefill_attention(
     ``pos0 .. pos0+C-1``; rope: the (C, half) tables built at offset
     ``pos0``; pool_k/pool_v: (L, P, Hkv, ps, D) page pools of every
     layer, carried by the layer scan; layer: () i32; page_tbl: the row's
-    (1, MP) table.  All C rotated k/v rows are scattered in place into
+    (1, MP) table.  With ``pos0`` a (R,) vector, x is (R, C, Dm): R
+    chunks, each at its own base position through its own page-table
+    row (R, MP), rope the (R, C, half) tables of
+    :func:`rope_tables_sliced_rows`; every row's k/v are written before
+    any row gathers, so two chunks of one request may share a dispatch.
+    All C rotated k/v rows are scattered in place into
     the row's page slots of this layer (:func:`paged_write`, with the
     chunk axis standing in for the batch axis — positions within a
     chunk are distinct, so rows never collide), then the layer's
@@ -484,25 +539,36 @@ def paged_prefill_attention(
     Earlier chunks' rows (and COW-shared prefix pages) are already in
     the pool, so a long prompt prefills chunk by chunk without a dense
     (1, P) cache.
-    Returns (out (1, C, Dm), (new_pool_k, new_pool_v)).
+    Returns (out (R, C, Dm), (new_pool_k, new_pool_v)).
     """
     q, k, v = project_qkv(b, x, w, prefix, n_heads, n_kv, qkv_bias)
     q = apply_rope(q, *rope)
     k = apply_rope(k, *rope)
-    Cq = x.shape[1]
+    R, Cq = x.shape[0], x.shape[1]
     MP = page_tbl.shape[1]
-    positions = ops.broadcast_to(pos0, (Cq,)) + ops.iota((Cq,), 0, "i32")
-    ptbl_c = ops.broadcast_to(page_tbl, (Cq, MP))
-    k_rows = ops.transpose(k, (2, 1, 0, 3))      # (C, Hkv, 1, D)
-    v_rows = ops.transpose(v, (2, 1, 0, 3))
-    pool_k = paged_write(pool_k, k_rows, ptbl_c, positions, page_size,
-                         layer)
-    pool_v = paged_write(pool_v, v_rows, ptbl_c, positions, page_size,
-                         layer)
+    if pos0.rank == 0:
+        positions = ops.broadcast_to(pos0, (Cq,)) + ops.iota((Cq,), 0, "i32")
+        ptbl_c = ops.broadcast_to(page_tbl, (Cq, MP))
+        k_rows = ops.transpose(k, (2, 1, 0, 3))      # (C, Hkv, 1, D)
+        v_rows = ops.transpose(v, (2, 1, 0, 3))
+        wpos = positions
+    else:       # R chunks: their R*C rows stand in for the batch axis
+        positions = ops.broadcast_in_dim(pos0, (R, Cq), (0,)) \
+            + ops.iota((R, Cq), 1, "i32")
+        wpos = ops.reshape(positions, (R * Cq,))
+        ptbl_c = ops.reshape(ops.broadcast_in_dim(page_tbl, (R, Cq, MP),
+                                                  (0, 2)), (R * Cq, MP))
+        Hkv, D = k.shape[1], k.shape[3]
+        k_rows = ops.reshape(ops.transpose(k, (0, 2, 1, 3)),
+                             (R * Cq, Hkv, 1, D))
+        v_rows = ops.reshape(ops.transpose(v, (0, 2, 1, 3)),
+                             (R * Cq, Hkv, 1, v.shape[3]))
+    pool_k = paged_write(pool_k, k_rows, ptbl_c, wpos, page_size, layer)
+    pool_v = paged_write(pool_v, v_rows, ptbl_c, wpos, page_size, layer)
     gk = paged_gather(pool_k, page_tbl, layer)
     gv = paged_gather(pool_v, page_tbl, layer)
     Skv = gk.shape[2]
-    kpos = ops.iota((1, Skv), 1, "i32")
+    kpos = ops.iota((R, Skv), 1, "i32")
     att = _chunkpos_attend(q, gk, gv, kpos, positions, n_heads=n_heads,
                            n_kv=n_kv, d_head=d_head, window=window)
     out = ops.matmul(merge_heads(att), b.cast(w[f"{prefix}wo"]))

@@ -19,8 +19,9 @@ Graph kinds:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ..configs.base import ModelConfig, ShapeConfig
 from ..core import ops
@@ -36,6 +37,9 @@ from . import xlstm as XL
 CACHE_SPEC = (None, "batch", "kv_heads", "kv_seq", None)  # (L,B,H,S,D)
 # paged pools have no batch axis: (L, pages, H, page_size, D)
 PAGED_CACHE_SPEC = (None, None, "kv_heads", None, None)
+# the families the paged serving programs (the decode chunk and the
+# prefill chunk the paged engine dispatches) are built for
+PAGED_FAMILIES = ("dense", "mla_moe")
 
 
 @dataclasses.dataclass
@@ -153,26 +157,135 @@ def _dense_block(b, cfg, h, w, rope, *, window=None, cache=None, pos=None,
     return h, extras
 
 
-def _layer_index(n_layers: int) -> Dict[str, Value]:
-    """The layer scan's xs entry that hands each layer its own index."""
-    return {"layer": ops.iota((n_layers,), 0, "i32")}
+def _layer_index(n_layers: int, first: int = 0) -> Dict[str, Value]:
+    """The layer scan's xs entry that hands each layer its own index
+    (``first`` onward: the stack's place in the page pools)."""
+    idx = ops.iota((n_layers,), 0, "i32")
+    if first:
+        idx = idx + ops.broadcast_to(ops.constant(first, dtype="i32"),
+                                     (n_layers,))
+    return {"layer": idx}
+
+
+@dataclasses.dataclass
+class _PagedStack:
+    """A run of layers of one kind in a paged program: its parameter
+    prefix, its length, the index of its first layer in the page pools,
+    its per-layer weights, and the block that runs one layer,
+    ``block(b, cfg, h, w, rope, pools, pos=, paged=, chunk=) -> (h,
+    pools, pairs)``.  A stack that ``counts`` returns per-token routed
+    pairs (:func:`~.moe.apply_routed`), summed over its layers."""
+    name: str
+    n: int
+    first: int
+    specs: C.Specs
+    inits: Dict
+    block: Callable
+    counts: bool = False
+
+
+def _dense_paged_block(b, cfg, h, w, rope, pools, *, pos, paged, chunk):
+    hh, pools = _dense_block(b, cfg, h, w, rope, window=cfg.window,
+                             cache=tuple(pools), pos=pos, paged=paged,
+                             chunk=chunk)
+    return hh, list(pools), None
+
+
+def _paged_stacks(cfg: ModelConfig) -> List[_PagedStack]:
+    if cfg.family == "dense":
+        specs, inits = _dense_layer_specs(cfg)
+        return [_PagedStack("layers", cfg.n_layers, 0, specs, inits,
+                            _dense_paged_block)]
+    if cfg.family == "mla_moe":
+        nd = cfg.first_dense
+        sd, idn = _v3_dense_specs(cfg)
+        sm, imo = _v3_routed_specs(cfg)
+        stacks = [
+            _PagedStack("dense", nd, 0, sd, idn,
+                        functools.partial(_v3_paged_block, moe=False)),
+            _PagedStack("moe", cfg.n_layers - nd, nd, sm, imo,
+                        functools.partial(_v3_paged_block, moe=True),
+                        counts=True)]
+        return [st for st in stacks if st.n]
+    raise NotImplementedError(
+        f"the paged serving programs are built for the {PAGED_FAMILIES} "
+        f"families, not {cfg.family!r} ({cfg.name})")
+
+
+def _paged_pools(cfg: ModelConfig, n_pages: int, page_size: int
+                 ) -> List[Tuple[str, Tuple[int, ...]]]:
+    """(name, shape) of each page pool: K and V pages of Hkv heads, or
+    MLA's two latent tensors in pages of one head."""
+    L = cfg.n_layers
+    if cfg.family == "mla_moe":
+        return [("cache_ckv", (L, n_pages, 1, page_size, cfg.kv_lora)),
+                ("cache_kr", (L, n_pages, 1, page_size, cfg.d_rope))]
+    shape = (L, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+    return [("cache_k", shape), ("cache_v", shape)]
+
+
+def _rotary(cfg: ModelConfig) -> Tuple[int, Optional[object]]:
+    """The rotated width and, for YaRN, its frequencies (None: plain)."""
+    if cfg.family != "mla_moe":
+        return cfg.head_dim, None
+    if cfg.yarn_factor > 1.0:
+        return cfg.d_rope, C.yarn_freq(
+            cfg.d_rope, cfg.rope_base, cfg.yarn_factor,
+            cfg.yarn_original_len, cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    return cfg.d_rope, None
 
 
 def _paged_body(b: ModelBuilder, cfg: ModelConfig, page_size: int,
-                chunk: bool = False):
-    """Layer-scan body of the paged programs.  The (L, P, Hkv, ps, D)
-    K and V pools are carries, not per-layer xs: each layer scatters its
-    new rows into them in place at its own index (``w["layer"]``) and
+                stack: _PagedStack, n_pools: int, chunk: bool = False):
+    """Layer-scan body of the paged programs.  The (L, P, H, ps, D)
+    pools are carries, not per-layer xs: each layer scatters its new
+    rows into them in place at its own index (``w["layer"]``) and
     gathers its view from the updated carry, so no layer copies or
-    re-stacks a pool slice.  consts: (cos, sin, pos, page_tbl)."""
+    re-stacks a pool slice.  A counting stack carries its per-token
+    routed pairs last.  consts: (cos, sin, pos, page_tbl)."""
     def body(carries, w, consts):
-        h, pool_k, pool_v = carries
-        hh, (pool_k, pool_v) = _dense_block(
-            b, cfg, h, w, (consts[0], consts[1]), window=cfg.window,
-            cache=(pool_k, pool_v), pos=consts[2],
+        h, pools = carries[0], carries[1:1 + n_pools]
+        hh, pools, pairs = stack.block(
+            b, cfg, h, w, (consts[0], consts[1]), pools, pos=consts[2],
             paged=(consts[3], page_size, w["layer"]), chunk=chunk)
-        return [hh, pool_k, pool_v], []
+        out = [hh] + list(pools)
+        if stack.counts:
+            out.append(carries[-1] + pairs)
+        return out, []
     return body
+
+
+def _paged_layers(b: ModelBuilder, cfg: ModelConfig, stacks, h: Value,
+                  pools: List[Value], consts: List[Value], page_size: int,
+                  chunk: bool, weights: Optional[List[List[Value]]] = None):
+    """Every stack's layer scan in turn over the carried pools.  With
+    ``weights`` None each stack declares its weights (``scan_blocks``);
+    else ``weights[i]`` holds stack i's stacked weights, already
+    declared.  Returns (h, pools, pairs: per-token routed pairs of the
+    counting stacks, or None)."""
+    pairs = None
+    for i, st in enumerate(stacks):
+        carries = [h] + list(pools)
+        if st.counts:
+            if pairs is None:
+                pairs = ops.broadcast_to(ops.constant(0, dtype="i32"),
+                                         (h.shape[0] * h.shape[1],))
+            carries.append(pairs)
+        if weights is None:
+            name, specs, inits = st.name, st.specs, st.inits
+            xs = _layer_index(st.n, st.first)
+        else:
+            name, specs, inits = f"chunk_{st.name}", {}, None
+            xs = dict(zip(st.specs, weights[i]))
+            xs.update(_layer_index(st.n, st.first))
+        outs, _ = b.scan_blocks(
+            name, st.n, specs,
+            _paged_body(b, cfg, page_size, st, len(pools), chunk),
+            carries, consts=consts, xs_extra=xs, weight_inits=inits)
+        h, pools = outs[0], list(outs[1:1 + len(pools)])
+        if st.counts:
+            pairs = outs[-1]
+    return h, pools, pairs
 
 
 def build_dense(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGraphs:
@@ -262,10 +375,9 @@ def build_dense(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGraphs
         h = _embed(b, cfg, token)
         cosr, sinr = C.rope_tables_rows(b, pos, dh, cfg.rope_base)
 
-        (h, ck, cv), _ = b.scan_blocks(
-            "layers", cfg.n_layers, specs, _paged_body(b, cfg, ps),
-            [h, ck, cv], consts=[cosr, sinr, pos, ptbl],
-            xs_extra=_layer_index(cfg.n_layers), weight_inits=inits)
+        h, (ck, cv), _ = _paged_layers(
+            b, cfg, _paged_stacks(cfg), h, [ck, cv],
+            [cosr, sinr, pos, ptbl], ps, chunk=False)
         logits = _final_logits(b, cfg, h, last_only=True)
         sample = C.sample_tokens(logits, temp, tk, key, pos)
         return ModelGraphs(cfg, kind, b.finish(
@@ -303,26 +415,29 @@ def build_dense(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGraphs
         {"cache_names": ["cache_k", "cache_v"]})
 
 
-def _dense_flat_params(b: ModelBuilder, cfg: ModelConfig, specs: C.Specs,
-                       inits: Dict):
-    """Declare the dense family's parameters flat (no scan_blocks), in
-    the decode/serve builders' declaration order — embed, stacked layer
-    weights, final norm, unembed — so ``init_params(seed)`` yields
-    weights identical to those builders'.  Stacked float weights are
-    pre-cast to the compute dtype (the chunk builders thread them into
-    the layer scan as xs).  Returns (table, stacked, gf, bf, wu)."""
+def _flat_params(b: ModelBuilder, cfg: ModelConfig,
+                 stacks: List[_PagedStack]):
+    """Declare a model's parameters flat (no scan_blocks), in the
+    decode/serve builders' declaration order — embed, each stack's
+    stacked layer weights, final norm, unembed — so ``init_params(seed)``
+    yields weights identical to those builders'.  Stacked float weights
+    are pre-cast to the compute dtype (the chunk builders thread them
+    into the layer scans as xs).  Returns (table, stacked: one list per
+    stack, gf, bf, wu)."""
     from ..core.types import is_float
 
     table = b.raw_param("embed/table", (cfg.vocab, cfg.d_model),
                         ("vocab", "embed"))
     stacked = []
-    for wname in list(specs):
-        shape_, logical = specs[wname]
-        v = b.raw_param(f"layers/{wname}", (cfg.n_layers,) + tuple(shape_),
-                        ("layers",) + tuple(logical), inits.get(wname))
-        if is_float(v.dtype):
-            v = ops.convert(v, b.compute_dtype)
-        stacked.append(v)
+    for st in stacks:
+        vals = []
+        for wname, (shape_, logical) in st.specs.items():
+            v = b.raw_param(f"{st.name}/{wname}", (st.n,) + tuple(shape_),
+                            ("layers",) + tuple(logical), st.inits.get(wname))
+            if is_float(v.dtype):
+                v = ops.convert(v, b.compute_dtype)
+            vals.append(v)
+        stacked.append(vals)
     gf = b.raw_param("final_norm/g", (cfg.d_model,), (None,), ones_init())
     bf = None
     if cfg.norm == "layernorm":
@@ -335,6 +450,20 @@ def _dense_flat_params(b: ModelBuilder, cfg: ModelConfig, specs: C.Specs,
     return table, stacked, gf, bf, wu
 
 
+def _paged_aux(pools, page_size: int, max_pages: int, n_pages: int,
+               pairs_output: Optional[int], stacks) -> Dict[str, object]:
+    names = [n for n, _ in pools]
+    aux = {"cache_names": names, "state_out_names": names,
+           "page_size": page_size, "max_pages": max_pages,
+           "n_pages": n_pages}
+    if pairs_output is not None:
+        # output ``pairs_output`` counts each token's routed (token,
+        # expert) pairs that landed on held experts, over ``moe_layers``
+        aux["pairs_output"] = pairs_output
+        aux["moe_layers"] = sum(st.n for st in stacks if st.counts)
+    return aux
+
+
 def _build_paged_chunk(cfg: ModelConfig, max_len: int, batch: int,
                        steps: int, page_size: int,
                        n_pages: Optional[int]) -> ModelGraphs:
@@ -344,8 +473,8 @@ def _build_paged_chunk(cfg: ModelConfig, max_len: int, batch: int,
     per-row position vector advancing in-graph.  See
     :func:`build_dense_chunk` for the contract."""
     b = ModelBuilder(cfg.param_dtype, cfg.compute_dtype)
-    L, dh = cfg.n_layers, cfg.head_dim
-    specs, inits = _dense_layer_specs(cfg)
+    stacks = _paged_stacks(cfg)
+    rdim, freq = _rotary(cfg)
     ps = int(page_size)
     mp = -(-max_len // ps)                 # logical pages per row
     P = int(n_pages) if n_pages is not None else 1 + batch * mp
@@ -355,40 +484,41 @@ def _build_paged_chunk(cfg: ModelConfig, max_len: int, batch: int,
     temp = b.input("temperature", (batch,), dtype="f32", spec=("batch",))
     tk = b.input("top_k", (batch,), spec=("batch",))
     key = b.input("key", (batch,), spec=("batch",))
-    ck = b.input("cache_k", (L, P, cfg.n_kv_heads, ps, dh),
-                 dtype=cfg.compute_dtype, spec=PAGED_CACHE_SPEC)
-    cv = b.input("cache_v", (L, P, cfg.n_kv_heads, ps, dh),
-                 dtype=cfg.compute_dtype, spec=PAGED_CACHE_SPEC)
-    table, stacked, gf, bf, wu = _dense_flat_params(b, cfg, specs, inits)
-    wnames = list(specs)
+    pools = _paged_pools(cfg, P, ps)
+    pool_in = [b.input(n, shape, dtype=cfg.compute_dtype,
+                       spec=PAGED_CACHE_SPEC) for n, shape in pools]
+    table, stacked, gf, bf, wu = _flat_params(b, cfg, stacks)
+    flat = [v for vals in stacked for v in vals]
 
     # outer-scan body: one serve_paged step on body-local parameters
     cp_tok = ops.parameter((batch, 1), "i32", "tok")
     cp_pos = ops.parameter((batch,), "i32", "pos")
-    cp_ck = ops.parameter(ck.shape, ck.dtype, "ck")
-    cp_cv = ops.parameter(cv.shape, cv.dtype, "cv")
-    const_vals = [ptbl, temp, tk, key, table] + stacked + [gf] \
+    cp_pools = [ops.parameter(v.shape, v.dtype, "c" + n.split("_")[-1])
+                for (n, _), v in zip(pools, pool_in)]
+    const_vals = [ptbl, temp, tk, key, table] + flat + [gf] \
         + ([bf] if bf is not None else []) + ([wu] if wu is not None else [])
     const_params = [ops.parameter(v.shape, v.dtype, f"w{i}")
                     for i, v in enumerate(const_vals)]
     cw = [p.out() for p in const_params]
     c_ptbl, c_temp, c_tk, c_key = cw[:4]
-    c_table, c_stacked = cw[4], cw[5:5 + len(stacked)]
-    c_gf = cw[5 + len(stacked)]
-    nxt = 6 + len(stacked)
+    c_table, c_flat = cw[4], cw[5:5 + len(flat)]
+    c_stacked, i = [], 0
+    for vals in stacked:
+        c_stacked.append(c_flat[i:i + len(vals)])
+        i += len(vals)
+    c_gf = cw[5 + len(flat)]
+    nxt = 6 + len(flat)
     c_bf = cw[nxt] if bf is not None else None
     c_wu = cw[-1] if wu is not None else None
 
     h = C.constrain(ops.gather(ops.convert(c_table, b.compute_dtype),
                                cp_tok.out(), axis=0), C.BATCH_SPEC)
-    cosr, sinr = C.rope_tables_rows(b, cp_pos.out(), dh, cfg.rope_base)
-
-    xs_extra = dict(zip(wnames, c_stacked))
-    xs_extra.update(_layer_index(L))
-    (h, new_ck, new_cv), _ = b.scan_blocks(
-        "chunk_layers", L, {}, _paged_body(b, cfg, ps),
-        [h, cp_ck.out(), cp_cv.out()],
-        consts=[cosr, sinr, cp_pos.out(), c_ptbl], xs_extra=xs_extra)
+    cosr, sinr = C.rope_tables_rows(b, cp_pos.out(), rdim, cfg.rope_base,
+                                    freq)
+    h, new_pools, pairs = _paged_layers(
+        b, cfg, stacks, h, [p.out() for p in cp_pools],
+        [cosr, sinr, cp_pos.out(), c_ptbl], ps, chunk=False,
+        weights=c_stacked)
     if cfg.norm == "layernorm":
         h = ops.layer_norm(h, c_gf, c_bf, eps=cfg.norm_eps)
     else:
@@ -400,19 +530,21 @@ def _build_paged_chunk(cfg: ModelConfig, max_len: int, batch: int,
     logits = C.constrain(ops.matmul(h, wun), ("batch", None, "vocab"))
     sample = C.sample_tokens(logits, c_temp, c_tk, c_key, cp_pos.out())
     new_pos = cp_pos.out() + ops.constant(1, dtype="i32")
-    body_fn = Function([cp_tok, cp_pos, cp_ck, cp_cv] + const_params,
-                       [sample, new_pos, new_ck, new_cv, sample],
+    ys = [sample] + ([pairs] if pairs is not None else [])
+    body_fn = Function([cp_tok, cp_pos] + cp_pools + const_params,
+                       [sample, new_pos] + new_pools + ys,
                        name=f"{cfg.name}_paged_chunk_body")
 
-    outs = ops.scan(body_fn, [token, pos, ck, cv], xs=[],
+    k = len(pools)
+    outs = ops.scan(body_fn, [token, pos] + pool_in, xs=[],
                     consts=const_vals, length=steps)
-    toks = outs[4]  # stacked ys: (steps, B, 1)
-    fn = b.finish([toks, outs[2], outs[3]], f"{cfg.name}_paged_chunk{steps}")
-    return ModelGraphs(cfg, "serve_paged_chunk", fn, b,
-                       {"cache_names": ["cache_k", "cache_v"],
-                        "state_out_names": ["cache_k", "cache_v"],
-                        "steps": steps, "page_size": ps, "max_pages": mp,
-                        "n_pages": P})
+    # stacked ys: tokens (steps, B, 1), then pairs (steps, B)
+    results = [outs[2 + k]] + outs[2:2 + k] + outs[3 + k:]
+    fn = b.finish(results, f"{cfg.name}_paged_chunk{steps}")
+    aux = _paged_aux(pools, ps, mp, P, 1 + k if pairs is not None else None,
+                     stacks)
+    aux["steps"] = steps
+    return ModelGraphs(cfg, "serve_paged_chunk", fn, b, aux)
 
 
 def build_dense_chunk(cfg: ModelConfig, max_len: int, batch: int,
@@ -455,10 +587,24 @@ def build_dense_chunk(cfg: ModelConfig, max_len: int, batch: int,
     admit/retire only at chunk boundaries (the engine re-dispatches with
     a refreshed page table), which is what keeps the hot loop at one
     dispatch per ``steps`` tokens per row.
+
+    The paged form is built for every family in :data:`PAGED_FAMILIES`.
+    For ``mla_moe`` the pools are MLA's two latent tensors
+    (``cache_ckv`` (L,P,1,ps,kv_lora), ``cache_kr`` (L,P,1,ps,d_rope)),
+    the dense layers and then the MoE layers scan over them with one
+    layer index, and a last output (``aux["pairs_output"]``) gives each
+    step's routed pairs per row, (steps, B) i32, summed over the MoE
+    layers (:func:`~.moe.apply_routed`).  The dense-cache form is dense
+    only.
     """
     if page_size is not None:
         return _build_paged_chunk(cfg, max_len, batch, steps,
                                   int(page_size), n_pages)
+    if cfg.family != "dense":
+        raise NotImplementedError(
+            f"the dense-cache decode chunk is built for the dense family, "
+            f"not {cfg.family!r}; pass page_size for the paged form "
+            f"({PAGED_FAMILIES})")
     b = ModelBuilder(cfg.param_dtype, cfg.compute_dtype)
     L, dh = cfg.n_layers, cfg.head_dim
     specs, inits = _dense_layer_specs(cfg)
@@ -468,7 +614,7 @@ def build_dense_chunk(cfg: ModelConfig, max_len: int, batch: int,
                  dtype=cfg.compute_dtype, spec=CACHE_SPEC)
     cv = b.input("cache_v", (L, batch, cfg.n_kv_heads, max_len, dh),
                  dtype=cfg.compute_dtype, spec=CACHE_SPEC)
-    table, stacked, gf, bf, wu = _dense_flat_params(b, cfg, specs, inits)
+    table, (stacked,), gf, bf, wu = _flat_params(b, cfg, _paged_stacks(cfg))
     wnames = list(specs)
 
     # outer-scan body: one full decode step on body-local parameters
@@ -530,7 +676,8 @@ def build_dense_chunk(cfg: ModelConfig, max_len: int, batch: int,
 
 def build_dense_paged_prefill(cfg: ModelConfig, max_len: int, chunk: int, *,
                               page_size: int,
-                              n_pages: Optional[int] = None) -> ModelGraphs:
+                              n_pages: Optional[int] = None,
+                              rows: Optional[int] = None) -> ModelGraphs:
     """One in-graph chunked-prefill dispatch for the paged engine.
 
     A (1, C) slice of a single request's prompt at base position ``pos``
@@ -554,38 +701,64 @@ def build_dense_paged_prefill(cfg: ModelConfig, max_len: int, chunk: int, *,
     Parameters are declared in the same order and under the same names
     as the serve/chunk builders, so the engine's existing weights bind
     by name.
+
+    Built for every family in :data:`PAGED_FAMILIES`; for ``mla_moe`` the
+    pools are the two latent tensors and a last output
+    (``aux["pairs_output"]``) gives the chunk's routed pairs per token,
+    (C,) i32 (see :func:`build_dense_chunk`).
+
+    With ``rows`` set, one dispatch prefills ``rows`` chunks of C tokens,
+    each at its own base position through its own page-table row, so
+    the weights are read once for all of them:
+
+    (token (R,C), pos (R,), page_tbl (R,MP), cache_k, cache_v, *W) ->
+        (logits (R,1,V), cache_k', cache_v'[, pairs (R*C,)])
+
+    Each row computes what the single-chunk program computes for it.  A
+    row whose page-table row is all zeros writes into the trash page and
+    its outputs are ignored; two rows may be consecutive chunks of one
+    prompt, since every row's rows are written before any row gathers.
     """
     b = ModelBuilder(cfg.param_dtype, cfg.compute_dtype)
-    L, dh = cfg.n_layers, cfg.head_dim
-    specs, inits = _dense_layer_specs(cfg)
+    stacks = _paged_stacks(cfg)
+    rdim, freq = _rotary(cfg)
     ps = int(page_size)
     mp = -(-max_len // ps)
-    P = int(n_pages) if n_pages is not None else 1 + mp
+    R = 1 if rows is None else int(rows)
+    P = int(n_pages) if n_pages is not None else 1 + R * mp
     Cn = int(chunk)
-    token = b.input("token", (1, Cn))
-    pos = b.input("pos", (), spec=())
-    ptbl = b.input("page_tbl", (1, mp), spec=("batch", None))
-    ck = b.input("cache_k", (L, P, cfg.n_kv_heads, ps, dh),
-                 dtype=cfg.compute_dtype, spec=PAGED_CACHE_SPEC)
-    cv = b.input("cache_v", (L, P, cfg.n_kv_heads, ps, dh),
-                 dtype=cfg.compute_dtype, spec=PAGED_CACHE_SPEC)
+    token = b.input("token", (R, Cn))
+    if rows is None:
+        pos = b.input("pos", (), spec=())
+    else:
+        pos = b.input("pos", (R,), spec=("batch",))
+    ptbl = b.input("page_tbl", (R, mp), spec=("batch", None))
+    pools = _paged_pools(cfg, P, ps)
+    pool_in = [b.input(n, shape, dtype=cfg.compute_dtype,
+                       spec=PAGED_CACHE_SPEC) for n, shape in pools]
     h = _embed(b, cfg, token)
     # slice the chunk's rows out of the same host-computed table the
     # dense prefill graph bakes in — bitwise-equal rope is what keeps
     # chunked prefill token-identical to dense prefill
-    cos, sin = C.rope_tables_sliced(b, max_len, dh, Cn, cfg.rope_base, pos)
+    if rows is None:
+        cos, sin = C.rope_tables_sliced(b, max_len, rdim, Cn, cfg.rope_base,
+                                        pos, freq)
+    else:
+        cos, sin = C.rope_tables_sliced_rows(b, max_len, rdim, Cn,
+                                             cfg.rope_base, pos, freq)
 
-    (h, ck, cv), _ = b.scan_blocks(
-        "layers", cfg.n_layers, specs, _paged_body(b, cfg, ps, chunk=True),
-        [h, ck, cv], consts=[cos, sin, pos, ptbl],
-        xs_extra=_layer_index(cfg.n_layers), weight_inits=inits)
+    h, pool_out, pairs = _paged_layers(b, cfg, stacks, h, pool_in,
+                                       [cos, sin, pos, ptbl], ps, chunk=True)
     logits = _final_logits(b, cfg, h, last_only=True)
-    fn = b.finish([logits, ck, cv], f"{cfg.name}_paged_prefill{Cn}")
-    return ModelGraphs(cfg, "prefill_paged", fn, b,
-                       {"cache_names": ["cache_k", "cache_v"],
-                        "state_out_names": ["cache_k", "cache_v"],
-                        "page_size": ps, "max_pages": mp, "n_pages": P,
-                        "chunk": Cn})
+    name = f"{cfg.name}_paged_prefill{Cn}" + ("" if rows is None
+                                               else f"x{R}")
+    fn = b.finish([logits] + pool_out + ([pairs] if pairs is not None
+                                         else []), name)
+    aux = _paged_aux(pools, ps, mp, P,
+                     1 + len(pools) if pairs is not None else None, stacks)
+    aux["chunk"] = Cn
+    aux["rows"] = R
+    return ModelGraphs(cfg, "prefill_paged", fn, b, aux)
 
 
 # =============================================================================
@@ -732,27 +905,91 @@ def _v3_moe_specs(cfg) -> Tuple[C.Specs, Dict]:
     return specs, inits
 
 
-def _v3_block(b, cfg, h, aux, w, rope, *, moe: bool, cache=None, pos=None):
+def _v3_rope(b: ModelBuilder, cfg: ModelConfig, seq: int,
+             offset: Optional[Value] = None) -> Tuple[Value, Value]:
+    """The family's cos/sin tables over ``d_rope``, YaRN's where the
+    config sets it (:func:`_rotary`)."""
+    return C.rope_tables(b, seq, cfg.d_rope, cfg.rope_base, offset=offset,
+                         freq=_rotary(cfg)[1])
+
+
+def _v3_scale(cfg: ModelConfig) -> float:
+    return MLA.softmax_scale(cfg.d_nope, cfg.d_rope, cfg.yarn_factor,
+                             cfg.yarn_mscale)
+
+
+def _v3_routed(b: ModelBuilder, cfg: ModelConfig, x: Value, w):
+    """The drop-free routed layer every serving graph of the family runs
+    (paged and lockstep): (out, per-token pairs on held experts)."""
+    return MOE.apply_routed(
+        b, x, w, prefix="moe_", n_experts=cfg.n_experts, top_k=cfg.top_k,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        scaling=cfg.routed_scaling, held=cfg.held_experts,
+        n_shared=cfg.n_shared_experts)
+
+
+def _v3_block(b, cfg, h, aux, w, rope, *, moe: bool, train: bool = False,
+              cache=None, pos=None):
+    """One layer of the train and lockstep graphs.  Training routes
+    through :func:`~.moe.apply_moe` and accumulates its balance loss in
+    ``aux``; prefill and decode route through the drop-free layer the
+    paged programs run (:func:`_v3_routed`), so every serving mode serves
+    one model."""
     xn = C.apply_norm(h, w, "ln1_", cfg.norm, cfg.norm_eps)
     att, extras = MLA.apply_mla(
         b, xn, w, prefix="attn_", n_heads=cfg.n_heads, q_lora=cfg.q_lora,
         kv_lora=cfg.kv_lora, d_nope=cfg.d_nope, d_rope=cfg.d_rope,
         d_v=cfg.d_v, rope=rope,
         cache_ckv=cache[0] if cache else None,
-        cache_kr=cache[1] if cache else None, pos=pos)
+        cache_kr=cache[1] if cache else None, pos=pos,
+        scale=_v3_scale(cfg), eps=cfg.norm_eps)
     h = h + att
     xn2 = C.apply_norm(h, w, "ln2_", cfg.norm, cfg.norm_eps)
-    if moe:
-        mo, a = MOE.apply_moe(b, xn2, w, prefix="moe_",
-                              n_experts=cfg.n_experts, top_k=cfg.top_k,
-                              capacity_factor=cfg.capacity_factor)
-        if cfg.n_shared_experts:
-            mo = mo + MOE.apply_shared_expert(b, xn2, w, "moe_")
-        h = h + mo
-        aux = aux + a
-    else:
-        h = h + C.apply_mlp(b, xn2, w, "mlp_")
-    return h, aux, extras
+    if not moe:
+        return h + C.apply_mlp(b, xn2, w, "mlp_"), aux, extras
+    if not train:
+        return h + _v3_routed(b, cfg, xn2, w)[0], aux, extras
+    mo, a = MOE.apply_moe(b, xn2, w, prefix="moe_",
+                          n_experts=cfg.n_experts, top_k=cfg.top_k,
+                          capacity_factor=cfg.capacity_factor)
+    if cfg.n_shared_experts:
+        mo = mo + MOE.apply_shared_expert(b, xn2, w, "moe_")
+    return h + mo, aux + a, extras
+
+
+def _v3_routed_specs(cfg) -> Tuple[C.Specs, Dict]:
+    """A MoE layer of the serving graphs: the router over every expert
+    and the weights of the ``held_experts`` this chip holds."""
+    sa, ia = _mla_attn_specs(cfg)
+    specs: C.Specs = {}
+    specs.update(_block_norm_specs(cfg, "ln1_"))
+    specs.update(sa)
+    specs.update(_block_norm_specs(cfg, "ln2_"))
+    specs.update(C.prefix_weights(
+        MOE.routed_specs(cfg.d_model, cfg.n_experts, cfg.held_experts,
+                         cfg.expert_d_ff, cfg.n_shared_experts), "moe_"))
+    inits = {**C.norm_inits("ln1_"), **ia, **C.norm_inits("ln2_"),
+             **MOE.routed_inits("moe_", cfg.n_shared_experts)}
+    return specs, inits
+
+
+def _v3_paged_block(b, cfg, h, w, rope, pools, *, pos, paged, chunk, moe):
+    """One layer of the paged programs: latent attention over the page
+    pools, then the dense MLP or the drop-free routed layer."""
+    page_tbl, page_size, layer = paged
+    xn = C.apply_norm(h, w, "ln1_", cfg.norm, cfg.norm_eps)
+    att, pools = MLA.paged_latent_attention(
+        b, xn, w, prefix="attn_", n_heads=cfg.n_heads, q_lora=cfg.q_lora,
+        kv_lora=cfg.kv_lora, d_nope=cfg.d_nope, d_rope=cfg.d_rope,
+        d_v=cfg.d_v, rope=rope, pool_ckv=pools[0], pool_kr=pools[1],
+        page_tbl=page_tbl, pos=pos, page_size=page_size, layer=layer,
+        scale=_v3_scale(cfg), eps=cfg.norm_eps, chunk=chunk)
+    h = h + att
+    xn2 = C.apply_norm(h, w, "ln2_", cfg.norm, cfg.norm_eps)
+    if not moe:
+        return h + C.apply_mlp(b, xn2, w, "mlp_"), list(pools), None
+    mo, pairs = _v3_routed(b, cfg, xn2, w)
+    return h + mo, list(pools), pairs
 
 
 def build_mla_moe(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGraphs:
@@ -760,7 +997,8 @@ def build_mla_moe(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGrap
     kind = shape.kind
     nd, nm = cfg.first_dense, cfg.n_layers - cfg.first_dense
     sd, idn = _v3_dense_specs(cfg)
-    sm, imo = _v3_moe_specs(cfg)
+    sm, imo = (_v3_moe_specs(cfg) if kind == "train"
+               else _v3_routed_specs(cfg))
 
     if kind in ("train", "prefill"):
         S = shape.seq_len
@@ -768,7 +1006,7 @@ def build_mla_moe(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGrap
         labels = b.input("labels", (batch, S)) if kind == "train" else None
         h = _embed(b, cfg, tokens)
         aux = ops.constant(0.0, dtype="f32")
-        cos, sin = C.rope_tables(b, S, cfg.d_rope, cfg.rope_base)
+        cos, sin = _v3_rope(b, cfg, S)
         want_kv = kind == "prefill"
 
         def dense_body(carries, w, consts):
@@ -778,7 +1016,8 @@ def build_mla_moe(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGrap
 
         def moe_body(carries, w, consts):
             hh, a2, ex = _v3_block(b, cfg, carries[0], carries[1], w,
-                                   (consts[0], consts[1]), moe=True)
+                                   (consts[0], consts[1]), moe=True,
+                                   train=kind == "train")
             return [hh, a2], list(ex) if want_kv else []
 
         (h, aux), ys_d = b.scan_blocks(
@@ -817,7 +1056,7 @@ def build_mla_moe(cfg: ModelConfig, shape: ShapeConfig, batch: int) -> ModelGrap
                     dtype=cfg.compute_dtype, spec=(None, "batch", "kv_seq", None))
     h = _embed(b, cfg, token)
     aux = ops.constant(0.0, dtype="f32")
-    cos, sin = C.rope_tables(b, 1, cfg.d_rope, cfg.rope_base, offset=pos)
+    cos, sin = _v3_rope(b, cfg, 1, offset=pos)
 
     def dense_body(carries, w, consts):
         hh, a2, ex = _v3_block(b, cfg, carries[0], carries[1], w,
@@ -860,7 +1099,7 @@ def _mtp_loss(b: ModelBuilder, cfg: ModelConfig, h: Value, tokens: Value,
     hm = ops.matmul(cat, wp)
     # one transformer block on hm
     specs, inits = _v3_dense_specs(cfg)
-    cos, sin = C.rope_tables(b, S - 1, cfg.d_rope, cfg.rope_base)
+    cos, sin = _v3_rope(b, cfg, S - 1)
 
     def body(carries, w, consts):
         hh, _, _ = _v3_block(b, cfg, carries[0],
@@ -1501,6 +1740,8 @@ def build_graphs(cfg: ModelConfig, shape: ShapeConfig,
         raise KeyError(f"unknown family {cfg.family}")
     if shape.kind in ("serve", "serve_paged") and cfg.family != "dense":
         raise NotImplementedError(
-            f"{shape.kind} (continuous-batching) graphs are only built for "
-            f"the dense family so far, not {cfg.family!r}")
+            f"{shape.kind} (single-step continuous-batching) graphs are "
+            f"built for the dense family, not {cfg.family!r}; the paged "
+            f"programs (build_dense_chunk(page_size=...), "
+            f"build_dense_paged_prefill) serve {PAGED_FAMILIES}")
     return _FAMILIES[cfg.family](cfg, shape, batch or shape.global_batch)

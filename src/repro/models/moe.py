@@ -1,7 +1,10 @@
 """Mixture-of-Experts layers in IR: GShard-style capacity-based token
 dispatch (top-k router -> scatter into per-expert buffers -> batched
 expert FFN -> weighted combine), plus the DeepSeek-V3 variant (shared
-expert + many small routed experts).
+expert + many small routed experts), and the drop-free expert-parallel
+layer the serving graphs run (:func:`apply_routed`): a router over
+every expert, the part of the result that the experts this chip holds
+give, no capacity.
 
 All of it is nGraph IR — TopK / CumSum / ScatterAdd / Gather / DotGeneral
 — so the same graph runs on the interpreter and compiles through the JAX
@@ -142,3 +145,110 @@ def apply_shared_expert(b: ModelBuilder, x: Value, w: Dict[str, Value],
     g = ops.silu(ops.matmul(x, b.cast(w[f"{prefix}ws_gate"])))
     u = ops.matmul(x, b.cast(w[f"{prefix}ws_up"]))
     return ops.matmul(g * u, b.cast(w[f"{prefix}ws_down"]))
+
+
+# -- the drop-free expert-parallel layer (paged serving) -----------------------
+def routed_specs(d_model: int, n_experts: int, held: int, expert_d_ff: int,
+                 n_shared: int = 0) -> Specs:
+    """The router keeps every expert's column (and the score-correction
+    bias); only the ``held`` experts' weights are stored."""
+    specs = moe_specs(d_model, held, expert_d_ff, n_shared, expert_d_ff)
+    specs["router"] = ((d_model, n_experts), ("embed", None))
+    specs["router_bias"] = ((n_experts,), (None,))
+    return specs
+
+
+# score-correction bias of the tiny presets' host init: large enough
+# beside the sigmoid scores' spread that it changes which experts win
+ROUTER_BIAS_SCALE = 0.1
+
+
+def routed_inits(prefix: str, n_shared: int = 0):
+    out = moe_inits(prefix, n_shared)
+    out[f"{prefix}router"] = fanin_init()
+    out[f"{prefix}router_bias"] = normal_init(ROUTER_BIAS_SCALE)
+    return out
+
+
+def route(logits: Value, bias: Value, *, top_k: int, n_group: int,
+          topk_group: int, scaling: float) -> Tuple[Value, Value]:
+    """The router on (T, E) f32 logits, as DeepSeek-V3's gate computes it.
+
+    ``s`` = sigmoid of the logits; the choice reads ``s + bias`` (the
+    score-correction bias, (E,)): a group's score is the sum of its two
+    largest, the ``topk_group`` best of ``n_group`` equal groups of
+    consecutive experts stay, and the top ``top_k`` experts among them
+    are chosen.  The weights read ``s`` alone, renormalized over the
+    chosen and scaled by ``scaling``.  Returns (weights (T, E) f32, zero
+    where an expert is not chosen; chosen (T, E) f32 in {0, 1})."""
+    T, E = logits.shape
+    s = ops.sigmoid(logits)
+    c = s + ops.broadcast_to(ops.reshape(bias, (1, E)), (T, E))
+    if n_group > 1:
+        G = n_group
+        top2, _ = ops.top_k(ops.reshape(c, (T, G, E // G)), 2)
+        _, gi = ops.top_k(ops.reduce_sum(top2, [-1]), topk_group)  # (T, tg)
+        keep = ops.reduce_sum(ops.one_hot(gi, G), [1])              # (T, G)
+        keep = ops.reshape(ops.broadcast_in_dim(keep, (T, G, E // G),
+                                                (0, 1)), (T, E))
+        c = ops.select(ops.greater(keep, ops.constant(0.0, dtype="f32")), c,
+                       ops.broadcast_to(ops.constant(-1e30, dtype="f32"),
+                                        (T, E)))
+    _, idx = ops.top_k(c, top_k)                                    # (T, K)
+    chosen = ops.reduce_sum(ops.one_hot(idx, E), [1])               # (T, E)
+    sc = s * chosen
+    denom = ops.reduce_sum(sc, [-1], keepdims=True)
+    w = sc * ops.broadcast_to(ops.constant(scaling, dtype="f32")
+                              / denom, (T, E))
+    return w, chosen
+
+
+def apply_routed(
+    b: ModelBuilder,
+    x: Value,  # (B, S, D) compute dtype, pre-normed
+    w: Dict[str, Value],
+    *,
+    prefix: str = "moe_",
+    n_experts: int,
+    top_k: int,
+    n_group: int = 1,
+    topk_group: int = 1,
+    scaling: float = 1.0,
+    first: int = 0,
+    held: Optional[int] = None,
+    n_shared: int = 0,
+) -> Tuple[Value, Value]:
+    """Expert-parallel routed layer without drops: the router (f32,
+    :func:`route`) runs over all ``n_experts``; this chip holds experts ``first ..
+    first + held - 1`` and adds, for every token, each held chosen
+    expert's output at its weight (the weights are over all chosen
+    experts, held or not), plus the shared expert.  No capacity applies:
+    every held expert computes every row, at weight zero where the row
+    did not choose it, which at serving batch sizes costs no more weight
+    reads than a gather.  The absent experts' part is not computed; the
+    chips that hold them would add it.
+
+    Returns (out (B, S, D), pairs (B*S,) i32: the (token, expert)
+    choices that landed on held experts)."""
+    B, S, D = x.shape
+    T = B * S
+    held = n_experts if held is None else held
+    xt = ops.reshape(x, (T, D))
+    logits = ops.matmul(ops.convert(xt, "f32"),
+                        ops.convert(w[f"{prefix}router"], "f32"))
+    wt, chosen = route(logits, ops.convert(w[f"{prefix}router_bias"], "f32"),
+                       top_k=top_k, n_group=n_group, topk_group=topk_group,
+                       scaling=scaling)
+    wh = ops.slice_(wt, [0, first], [T, first + held])              # (T, Eh)
+    pairs = ops.convert(ops.reduce_sum(
+        ops.slice_(chosen, [0, first], [T, first + held]), [-1]), "i32")
+    g = ops.silu(ops.einsum("td,edf->etf", xt,
+                            b.cast(w[f"{prefix}we_gate"])))
+    u = ops.einsum("td,edf->etf", xt, b.cast(w[f"{prefix}we_up"]))
+    whe = ops.convert(ops.transpose(wh, (1, 0)), x.dtype)           # (Eh, T)
+    h = g * u * ops.broadcast_in_dim(whe, g.shape, (0, 1))
+    out = ops.einsum("etf,efd->td", h, b.cast(w[f"{prefix}we_down"]))
+    out = ops.reshape(out, (B, S, D))
+    if n_shared:
+        out = out + apply_shared_expert(b, x, w, prefix)
+    return constrain(out, ("batch", None, None)), pairs

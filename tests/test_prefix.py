@@ -208,14 +208,17 @@ def test_cancel_mid_prefill_releases_shared_pages():
     decoding and every page returns on drain."""
     rng = np.random.default_rng(5)
     base = rng.integers(0, CFG.vocab, size=(8,)).astype(np.int32)
+    # the sharer's 36 own tokens are nine chunks: a batched dispatch
+    # runs four of them a step, so two steps after it attaches one is
+    # still to run
     longp = np.concatenate(
-        [base, rng.integers(0, CFG.vocab, size=(8,)).astype(np.int32)])
-    solo = ServeEngine(CFG, slots=2, max_len=24, mode="paged", seed=0,
+        [base, rng.integers(0, CFG.vocab, size=(36,)).astype(np.int32)])
+    solo = ServeEngine(CFG, slots=2, max_len=48, mode="paged", seed=0,
                        page_size=4, prefill_chunk=4)
     rid = solo.submit(base, 8)
     ref = [int(t) for t in solo.run().results[rid]]
 
-    eng = ServeEngine(CFG, slots=2, max_len=24, mode="paged", seed=0,
+    eng = ServeEngine(CFG, slots=2, max_len=48, mode="paged", seed=0,
                       page_size=4, prefill_chunk=4)
     rp = eng.submit(base, 8)
     rl = eng.submit(longp, 4)

@@ -100,9 +100,10 @@ SCRIPT = textwrap.dedent("""
 
     # -- 3) cancel mid-prefill releases shared pages under tp=2 ---------
     base = rng.integers(0, CFG.vocab, size=(8,)).astype(np.int32)
+    # nine chunks of the sharer's own: four a step in a batched dispatch
     longp = np.concatenate(
-        [base, rng.integers(0, CFG.vocab, size=(8,)).astype(np.int32)])
-    kw = dict(slots=2, max_len=24, chunk_steps=4, prefill_chunk=4)
+        [base, rng.integers(0, CFG.vocab, size=(36,)).astype(np.int32)])
+    kw = dict(slots=2, max_len=48, chunk_steps=4, prefill_chunk=4)
     solo = eng(2, **kw)
     rid = solo.submit(base, 8)
     ref = [int(t) for t in solo.run().results[rid]]

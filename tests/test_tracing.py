@@ -123,9 +123,11 @@ def test_served_requests_record_every_documented_span():
     for name in ("prefill.prep", "prefill.dispatch", "prefill.sync",
                  "prefill.sample"):
         assert {s.rid for s in named[name]} == rids, name
-    # three chunks of at most four tokens per ten-token prompt, one
+    # a ten-token prompt is two whole chunks of four, which share one
+    # batched dispatch (with another prompt's where both were admitted
+    # in one step), and a two-token tail dispatched alone; one
     # first-token sample each
-    assert len(named["prefill.dispatch"]) == 3 * n
+    assert -(-2 * n // 4) + n <= len(named["prefill.dispatch"]) <= 2 * n
     assert len(named["prefill.sample"]) == n
 
     tot = metrics["engine"]["spans"]
